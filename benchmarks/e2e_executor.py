@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import CompileSpec, build_plan, compile as smof_compile
+from repro.compile_cache import enable_compile_cache
 from repro.core import DSEConfig, EXEC_MODELS
 from repro.core.resources import Device
 from repro.memory import POLICIES, ChannelConfig
@@ -326,6 +327,7 @@ def main(argv: list[str] | None = None) -> None:
                          "'both' emits comparable reference and pallas "
                          "rows per bench point (default auto)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.autotune:
         run_autotune(smoke=args.smoke, microbatches=args.microbatches,

@@ -79,6 +79,8 @@ def main(argv: list[str] | None = None) -> None:
                          "bench point (default auto)")
     args = ap.parse_args(argv)
     smoke = args.smoke
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (baseline, e2e_executor, fig6_ablation, fig7_compression,
                    fig8_variability, kernels_bench, roofline, table3_models,
                    table4_partitioning, table5_throughput)

@@ -92,7 +92,7 @@ class CompileSpec:
     plan: ExecutionPlan | None = None  # strategy="manual-plan" input
     dse: DSEConfig | None = None       # strategy="dse" knobs
     interpret: bool | None = None      # Pallas interpret-mode override
-    placement: str = "auto"            # pipelined: interleave | shard_map
+    placement: str = "interleave"      # pipelined: interleave | shard_map
     obs: ObsConfig = dataclasses.field(default_factory=ObsConfig)
     #: opt-in off-chip channel model (``repro.memory``): arbitration
     #: policy + optional gbps override; pipelined lowerings then carry the
@@ -515,12 +515,14 @@ class Compiled:
                 if d.get("plan") is not None else None)
         model = (Graph.from_json_dict(d["graph"]) if d.get("graph")
                  else d["model"])
+        placement = d.get("placement", "interleave")
+        if placement == "auto":     # older artifacts: the one-device scan
+            placement = "interleave"
         spec = CompileSpec(
             model=model, device=d["device"], strategy="manual-plan",
             mode=d["mode"], kernel_mode=d["kernel_mode"],
             microbatches=d["microbatches"], seed=d["seed"],
-            interpret=d.get("interpret"),
-            placement=d.get("placement", "auto"), plan=plan,
+            interpret=d.get("interpret"), placement=placement, plan=plan,
             obs=ObsConfig.from_dict(d.get("obs", {})),
             channel=(ChannelConfig.from_dict(d["channel"])
                      if d.get("channel") else None))

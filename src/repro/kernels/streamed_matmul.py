@@ -86,6 +86,12 @@ def _round_up(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
 
 
+def splits_weight(k: int) -> bool:
+    """Whether a ``k``-row weight has a dynamic region to stream: more than
+    one 128-row panel after padding.  Smaller weights run as a plain dot."""
+    return _round_up(k, 128) > 128
+
+
 def streamed_matmul_padded(x: jax.Array, w: jax.Array, *,
                            static_fraction: float = 0.5, bm: int = 128,
                            bk: int = 128, bn: int = 128,
@@ -105,10 +111,10 @@ def streamed_matmul_padded(x: jax.Array, w: jax.Array, *,
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     Mp, Np = _round_up(M, bm), _round_up(N, bn)
-    Kp = _round_up(K, 128)
-    if Kp <= 128:
+    if not splits_weight(K):
         return jnp.dot(x, w, preferred_element_type=jnp.float32
                        ).astype(x.dtype)
+    Kp = _round_up(K, 128)
     ks = int(round(static_fraction * Kp / 128.0)) * 128
     ks = max(min(ks, Kp - bk), 128)   # >= one static panel + one dyn block
     kd = _round_up(Kp - ks, bk)

@@ -43,12 +43,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .bfp8 import bfp8_dequant_values, bfp8_quant_values
+from .bfp8 import _pad_rows, bfp8_dequant_values, bfp8_quant_values
 from .streamed_matmul import _round_up
 
 DEFAULT_BM = 128            # row-block default (positions per grid step)
 DEFAULT_BC = 128            # out-channel-block default (conv family only)
+BM_ALIGN = 8                # a row block is whole sublane tiles ...
+BC_ALIGN = 128              # ... and a channel block whole lane tiles
 
 # Module-level codec indirection: the fused kernels look these up at trace
 # time, so the differential fuzzer's fault injector can skew the *fused*
@@ -58,15 +61,12 @@ _quant_vals = bfp8_quant_values
 _dequant_vals = bfp8_dequant_values
 
 
-def _tile(n: int, b: int, default: int) -> int:
-    """Resolve a tile size: 0 means 'auto' (default, clamped to the axis)."""
-    b = b if b > 0 else default
+def _tile(n: int, b: int, default: int, align: int) -> int:
+    """Resolve a tile size Mosaic accepts: 0 means the default; a request
+    rounds up to a multiple of ``align`` (8 rows / 128 lanes), and a tile
+    that covers the axis becomes the full axis."""
+    b = _round_up(b if b > 0 else default, align)
     return min(b, n) if n > 0 else b
-
-
-def _pad_rows(x: jax.Array, mp: int) -> jax.Array:
-    m = x.shape[0]
-    return x if m == mp else jnp.pad(x, ((0, mp - m), (0, 0)))
 
 
 def _pad_payload(payload, mp: int):
@@ -124,11 +124,11 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
     else:
         m = x.shape[0]
         assert x.shape[1] == cin, (x.shape, w.shape)
-    bm = _tile(m, bm, DEFAULT_BM)
+    bm = _tile(m, bm, DEFAULT_BM, BM_ALIGN)
     mp = _round_up(m, bm)
 
     if not encode:
-        bc = _tile(n, bc, DEFAULT_BC)
+        bc = _tile(n, bc, DEFAULT_BC, BC_ALIGN)
         npad = _round_up(n, bc)
         wp = jnp.pad(w, ((0, 0), (0, npad - n)))
         grid = (mp // bm, npad // bc)
@@ -189,37 +189,39 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
 # dwconv — depthwise temporal conv, 'same' padding, halo rows via pl.ds
 # =============================================================================
 
-def _dw_mix(xp, w, base, bm, taps):
+def _dw_mix(w, xs_ref, base, bm, taps):
     """The reference tap sum on a row tile: ``sum`` in the same order as
-    ``runtime.executor._dwconv`` so the accumulation is bit-identical."""
-    return sum(w[k][None, :] *
-               jax.lax.dynamic_slice_in_dim(xp, base + k, bm, axis=0)
+    ``runtime.executor._dwconv`` so the accumulation is bit-identical.
+    The taps are overlapping ``(bm, c)`` windows of the 'same'-padded
+    input, read from a ref with ``pl.ds`` (BlockSpecs cannot overlap)."""
+    return sum(w[k][None, :] * xs_ref[pl.ds(base + k, bm), :]
                for k in range(taps))
 
 
+def _dw_decode_window(man_ref, exp_ref, xs_ref, *, block, c, bm, taps):
+    """Dequantise this grid step's ``bm + taps - 1`` halo rows of the
+    row-padded spill payload into the f32 scratch the taps read.  Zero
+    payload rows decode to exact zeros, so the padding is the 'same' pad."""
+    rows = pl.ds(pl.program_id(0) * bm, bm + taps - 1)
+    xs_ref[...] = _dequant_vals(man_ref[rows, :], exp_ref[rows, :],
+                                block=block)[:, :c]
+
+
 def _dwconv_kernel(xp_ref, w_ref, o_ref, *, bm, taps):
-    base = pl.program_id(0) * bm
-    w = w_ref[...]
-    # halo read: taps overlapping (bm, c) windows from the un-blocked,
-    # 'same'-padded input — BlockSpecs cannot overlap, pl.ds can
-    o_ref[...] = sum(w[k][None, :] * xp_ref[pl.ds(base + k, bm), :]
-                     for k in range(taps))
+    o_ref[...] = _dw_mix(w_ref[...], xp_ref, pl.program_id(0) * bm, bm,
+                         taps)
 
 
-def _dwconv_dec_kernel(man_ref, exp_ref, w_ref, o_ref, *, block, c, bm,
-                       taps, mp):
-    x = _dequant_vals(man_ref[...], exp_ref[...], block=block)[:, :c]
-    pad = taps // 2
-    xp = jnp.pad(x, ((pad, (taps - 1 - pad) + (mp - x.shape[0])), (0, 0)))
-    o_ref[...] = _dw_mix(xp, w_ref[...], pl.program_id(0) * bm, bm, taps)
+def _dwconv_dec_kernel(man_ref, exp_ref, w_ref, o_ref, xs_ref, *, block, c,
+                       bm, taps):
+    _dw_decode_window(man_ref, exp_ref, xs_ref, block=block, c=c, bm=bm,
+                      taps=taps)
+    o_ref[...] = _dw_mix(w_ref[...], xs_ref, 0, bm, taps)
 
 
 def _dwconv_enc_kernel(xp_ref, w_ref, o_ref, man_ref, exp_ref, *, block,
                        bm, taps):
-    base = pl.program_id(0) * bm
-    w = w_ref[...]
-    y = sum(w[k][None, :] * xp_ref[pl.ds(base + k, bm), :]
-            for k in range(taps))
+    y = _dw_mix(w_ref[...], xp_ref, pl.program_id(0) * bm, bm, taps)
     o_ref[...] = y
     c = y.shape[1]
     yq = jnp.pad(y, ((0, 0), (0, _round_up(c, block) - c)))
@@ -227,11 +229,10 @@ def _dwconv_enc_kernel(xp_ref, w_ref, o_ref, man_ref, exp_ref, *, block,
 
 
 def _dwconv_dec_enc_kernel(man_ref, exp_ref, w_ref, o_ref, yman_ref,
-                           yexp_ref, *, block, c, bm, taps, mp):
-    x = _dequant_vals(man_ref[...], exp_ref[...], block=block)[:, :c]
-    pad = taps // 2
-    xp = jnp.pad(x, ((pad, (taps - 1 - pad) + (mp - x.shape[0])), (0, 0)))
-    y = _dw_mix(xp, w_ref[...], pl.program_id(0) * bm, bm, taps)
+                           yexp_ref, xs_ref, *, block, c, bm, taps):
+    _dw_decode_window(man_ref, exp_ref, xs_ref, block=block, c=c, bm=bm,
+                      taps=taps)
+    y = _dw_mix(w_ref[...], xs_ref, 0, bm, taps)
     o_ref[...] = y
     yq = jnp.pad(y, ((0, 0), (0, _round_up(c, block) - c)))
     yman_ref[...], yexp_ref[...] = _quant_vals(yq, block=block)
@@ -254,7 +255,7 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
     else:
         m = x.shape[0]
         assert x.shape[1] == c, (x.shape, w.shape)
-    bm = _tile(m, bm, DEFAULT_BM)
+    bm = _tile(m, bm, DEFAULT_BM, BM_ALIGN)
     mp = _round_up(m, bm)
     pad = taps // 2
     cq = _round_up(c, block)
@@ -286,23 +287,27 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
         return y[:m], (man_o[:m], exp_o[:m])
 
     # ingress-fused: the payload stays un-blocked too (the decode is
-    # row-local but the halo needs neighbouring rows)
-    in_specs = [pl.BlockSpec((m, c_pad), lambda i: (0, 0)),
-                pl.BlockSpec((m, c_pad // block), lambda i: (0, 0)),
+    # row-local but the halo needs neighbouring rows).  It is row-padded
+    # like ``xp`` above: zero mantissas decode to the zero 'same' pad.
+    rows = ((pad, (taps - 1 - pad) + (mp - m)), (0, 0))
+    man, exp = jnp.pad(man, rows), jnp.pad(exp, rows)
+    in_specs = [pl.BlockSpec(man.shape, lambda i: (0, 0)),
+                pl.BlockSpec(exp.shape, lambda i: (0, 0)),
                 pl.BlockSpec((taps, c), lambda i: (0, 0))]
+    scratch = [pltpu.VMEM((bm + taps - 1, c), jnp.float32)]
     if not encode:
         y = pl.pallas_call(
             functools.partial(_dwconv_dec_kernel, block=block, c=c, bm=bm,
-                              taps=taps, mp=mp),
+                              taps=taps),
             grid=grid, in_specs=in_specs,
             out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
-            interpret=interpret)(man, exp, w)
+            scratch_shapes=scratch, interpret=interpret)(man, exp, w)
         return y[:m]
     y, man_o, exp_o = pl.pallas_call(
         functools.partial(_dwconv_dec_enc_kernel, block=block, c=c, bm=bm,
-                          taps=taps, mp=mp),
-        grid=grid, in_specs=in_specs,
+                          taps=taps),
+        grid=grid, in_specs=in_specs, scratch_shapes=scratch,
         out_specs=[pl.BlockSpec((bm, c), lambda i: (i, 0)),
                    pl.BlockSpec((bm, cq), lambda i: (i, 0)),
                    pl.BlockSpec((bm, cq // block), lambda i: (i, 0))],
@@ -362,7 +367,7 @@ def pool(x, m_out: int, *, c: int | None = None, payload=None, encode=False,
     if m % m_out:
         raise ValueError(f"pool needs m_out | m, got {m} -> {m_out}")
     k = m // m_out
-    bo = _tile(m_out, bm, DEFAULT_BM)
+    bo = _tile(m_out, bm, DEFAULT_BM, BM_ALIGN)
     mop = _round_up(m_out, bo)
     cq = _round_up(c, block)
     grid = (mop // bo,)
@@ -443,7 +448,7 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
         assert c_pad == _round_up(c, block), (man.shape, c, block)
     else:
         m, c = x.shape
-    bm = _tile(m, bm, DEFAULT_BM)
+    bm = _tile(m, bm, DEFAULT_BM, BM_ALIGN)
     mp = _round_up(m, bm)
     cq = _round_up(c, block)
     grid = (mp // bm,)

@@ -63,9 +63,11 @@ MOVES = ("split", "merge", "evict", "unevict", "frag", "tile")
 # candidate Pallas tile sizes for the "tile" move (0 = kernel default).
 # Results are tile-independent (bit-exact — tests/test_properties.py), so
 # these are pure performance knobs; only proposed when the resolved kernel
-# mode actually dispatches to the streaming_conv Pallas bodies.
+# mode actually dispatches to the streaming_conv Pallas bodies.  Every
+# choice is a tile Mosaic accepts: rows in multiples of 8, channels in
+# multiples of 128 (streaming_conv._tile).
 TILE_BM_CHOICES = (0, 8, 16, 32, 64, 128)
-TILE_BC_CHOICES = (0, 32, 64, 128)
+TILE_BC_CHOICES = (0, 128, 256)
 
 
 @dataclasses.dataclass

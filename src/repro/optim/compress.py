@@ -102,13 +102,6 @@ def make_pod_compressed_grad_fn(loss_fn, mesh):
     # manual over the pod axis only; data/model stay GSPMD-auto
     in_specs = (P(), P("pod"), P())
     out_specs = (P(), P(), P())
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(per_pod, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names={"pod"})
-    # pre-0.7 jax: shard_map lives in jax.experimental and spells the
-    # manual/auto split as `auto=` and replication checking as `check_rep=`
-    from jax.experimental.shard_map import shard_map
-    return shard_map(per_pod, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False,
-                     auto=frozenset(mesh.axis_names) - {"pod"})
+    return jax.shard_map(per_pod, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names={"pod"})

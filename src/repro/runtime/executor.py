@@ -9,10 +9,10 @@ this module makes those decisions actually happen on an accelerator:
   and dequantised on the way back in (``kernels/bfp8.py``), so the executed
   numerics carry the codec's error exactly as hardware would; RLE/Huffman
   are lossless, so their numerical effect is identity and only the traffic
-  accounting changes.  On TPU the spill additionally hops through
-  ``pinned_host`` memory via ``jax.device_put`` so the bytes truly leave
-  HBM; elsewhere the hop is a no-op (the round-trip through the codec still
-  executes).
+  accounting changes.  On TPU the spill additionally hops through the
+  host's pinned memory (``jax.device_put`` to ``jax.memory.Space.Host``
+  and back) so the bytes truly leave HBM; elsewhere the hop is a no-op
+  (the round-trip through the codec still executes).
 * **fragmented weights** (``LayerPlan.weight_static_fraction < 1``)
   dispatch to ``kernels/streamed_matmul.py``: the static row-panel of the
   weight matrix is pinned in VMEM and the dynamic remainder streams from
@@ -69,7 +69,8 @@ from ..core.plan import ExecutionPlan
 from ..kernels import ref as kref
 from ..kernels import streaming_conv as SC
 from ..kernels.bfp8 import bfp8_dequant, bfp8_quant
-from ..kernels.streamed_matmul import _round_up, streamed_matmul_padded
+from ..kernels.streamed_matmul import (_round_up, splits_weight,
+                                      streamed_matmul_padded)
 
 WEIGHT_KINDS = ("conv", "deconv", "matmul")
 TEMPORAL_KINDS = ("dwconv",)
@@ -412,6 +413,21 @@ def apply_vertex(v, ins: list[jax.Array], params: dict, x: jax.Array | None,
 FUSABLE_KINDS = ("conv", "deconv", "matmul", "dwconv", "pool", "act")
 
 
+def vertex_body(g: Graph, name: str, an: PlanAnalysis) -> str:
+    """Which body :func:`apply_vertex` runs for one vertex: ``"pallas"`` or
+    ``"reference"``.  Pallas mode still runs reference bodies for the
+    data-movement kinds and for a fragmented weight too small to split
+    (``streamed_matmul_padded``'s plain-dot fallback) — this names them,
+    so a silent fallback shows up in a script's output."""
+    v = g.vertex(name)
+    if not an.use_pallas or v.kind not in FUSABLE_KINDS:
+        return "reference"
+    if v.kind in WEIGHT_KINDS and an.frac.get(name, 1.0) < 1.0:
+        return ("pallas" if splits_weight(_exec_spec(g, name)["cin"])
+                else "reference")
+    return "pallas"
+
+
 @dataclasses.dataclass(frozen=True)
 class VertexLowering:
     """``_lower_vertex``'s decision record for one vertex under the
@@ -588,31 +604,46 @@ class LoweredPipeline:
 def resolve_kernel_mode(kernel_mode: str,
                         interpret: bool | None) -> tuple[bool, bool]:
     """Kernel-dispatch policy shared by both executors: returns
-    (use_pallas, interpret) for a requested mode on the current backend."""
+    (use_pallas, interpret) for a requested mode on the current backend.
+
+    On a TPU the Pallas kernels run compiled: an explicit
+    ``interpret=True`` (e.g. replayed from an artifact saved on a CPU
+    host) is refused rather than silently timing the interpreter."""
     if kernel_mode not in ("auto", "pallas", "reference"):
         raise ValueError(f"unknown kernel_mode {kernel_mode!r}")
     on_tpu = jax.default_backend() == "tpu"
     use_pallas = kernel_mode == "pallas" or (kernel_mode == "auto" and on_tpu)
     if interpret is None:
         interpret = not on_tpu
+    elif interpret and use_pallas and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU would run the Pallas kernels in the "
+            "interpreter instead of on the chip; compile with "
+            "interpret=None (or False)")
     return use_pallas, interpret
 
 
 def _make_offchip_hop() -> Callable[[jax.Array], jax.Array]:
-    """Best-effort real off-chip placement: route the value through host
-    memory when the backend exposes a host memory kind (TPU); identity
-    elsewhere.  Called once at lowering time, not per trace."""
-    try:
-        from jax._src.sharding_impls import TransferToMemoryKind
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-        if "pinned_host" in kinds and jax.default_backend() == "tpu":
-            def hop(x: jax.Array) -> jax.Array:
-                y = jax.device_put(x, TransferToMemoryKind("pinned_host"))
-                return jax.device_put(y, TransferToMemoryKind("device"))
-            return hop
-    except Exception:       # pragma: no cover - jax-internal API moved
-        pass
-    return lambda x: x
+    """Real off-chip placement for an evicted spill: on TPU the value is
+    moved to the host's pinned memory and back inside the jitted step
+    (``jax.device_put`` to ``jax.memory.Space.Host`` / ``.Device``), so the
+    bytes truly leave HBM.  Other platforms have no separate device memory
+    to leave, and the hop is the identity.  A TPU without a ``pinned_host``
+    memory kind is an error, not a silent identity.  Called once at
+    lowering time, not per trace."""
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return lambda x: x
+    kinds = {m.kind for m in device.addressable_memories()}
+    if "pinned_host" not in kinds:
+        raise RuntimeError(
+            f"{device.device_kind} exposes no pinned_host memory (kinds: "
+            f"{sorted(kinds)}): evicted spills cannot leave HBM")
+
+    def hop(x: jax.Array) -> jax.Array:
+        y = jax.device_put(x, jax.memory.Space.Host)
+        return jax.device_put(y, jax.memory.Space.Device)
+    return hop
 
 
 def lower_plan(g: Graph, plan: ExecutionPlan | None = None, *,

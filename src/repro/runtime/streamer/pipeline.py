@@ -5,7 +5,8 @@ the same per-vertex lowering, via ``runtime.executor.analyze_plan`` /
 ``apply_vertex``) as the sequential executor, but runs the plan's stages as
 a software 1F1B pipeline over ``B`` microbatches:
 
-* **single device** — one ``jax.lax.scan`` over ``T = B + S - 1`` ticks.
+* **single device** (``placement="interleave"``, the default) — one
+  ``jax.lax.scan`` over ``T = B + S - 1`` ticks.
   The carry holds, per stage-crossing edge, a shift register of the
   *encoded* spill (BFP8 mantissas + shared exponents for ``bfp8`` streams,
   raw words otherwise): stage ``i`` pushes microbatch ``b``'s encoded spill
@@ -15,7 +16,8 @@ a software 1F1B pipeline over ``B`` microbatches:
   (XLA can fuse/overlap them) and the spill round-trip is off the critical
   path of its own microbatch.
 
-* **devices >= stages** — a ``shard_map`` ring pipeline: each device owns
+* **one device per stage** (``placement="shard_map"``, asked for
+  explicitly) — a ``shard_map`` ring pipeline: each device owns
   one stage, crossing edges live in per-device transit slots that
   ``ppermute`` one hop per tick, so a spill produced on stage ``i`` arrives
   at stage ``k`` exactly ``k - i`` ticks later while both devices compute.
@@ -49,6 +51,7 @@ from ..executor import (BFP8_BLOCK, TEMPORAL_KINDS, PlanAnalysis, SpillReport,
 from . import queues as Q
 from . import schedule as SCH
 
+PLACEMENTS = ("interleave", "shard_map")
 
 # =============================================================================
 # StreamReport
@@ -211,6 +214,9 @@ def _crossing_edges(g: Graph, an: PlanAnalysis) -> list[tuple[str, str]]:
 def _make_stage_fns(g: Graph, an: PlanAnalysis, names: list[list[str]],
                     crossing: list[tuple[str, str]], hop, enc):
     """Per-stage callables with a uniform signature.
+
+    ``hop`` moves the stage's evicted spills, and the payloads it hands to
+    later stages, off-chip and back.
 
     ``fn_j(params, x, reads) -> (produced, y)`` where ``reads`` maps every
     crossing edge to its decoded value (stage ``j`` only touches the ones it
@@ -472,15 +478,16 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
                          microbatches: int | None = None,
                          kernel_mode: str = "auto", seed: int = 0,
                          interpret: bool | None = None,
-                         placement: str = "auto",
+                         placement: str = "interleave",
                          channel: ChannelConfig | None = None,
                          device=None) -> StreamingExecutor:
     """Lower ``plan`` over ``g`` to a pipelined multi-microbatch executor.
 
     microbatches: length ``B`` of the input stream the jitted step is traced
     for (defaults to ``plan.microbatch``, floored at 1).
-    placement: "interleave" (single-device scan), "shard_map" (one stage per
-    device), or "auto" (shard_map when ``devices >= stages > 1``).
+    placement: "interleave" (single-device scan) or "shard_map" (one stage
+    per device).  The choice is the caller's: a host with spare devices
+    does not move a plan onto the ring by itself.
     channel: opt-in off-chip channel model (``repro.memory``): the plan's
     streams are arbitrated over the shared port, queue capacities absorb
     the arbiter-derived crossing delays, and the report carries the
@@ -502,11 +509,9 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
     sched = SCH.build_schedule(S, B)
     hop = _make_offchip_hop()
 
-    if placement not in ("auto", "interleave", "shard_map"):
-        raise ValueError(f"unknown placement {placement!r}")
-    if placement == "auto":
-        placement = ("shard_map" if S > 1 and len(jax.devices()) >= S
-                     else "interleave")
+    if placement not in PLACEMENTS:
+        raise ValueError(f"unknown placement {placement!r}; pick one of "
+                         f"{PLACEMENTS}")
     if placement == "shard_map" and len(jax.devices()) < S:
         raise ValueError(f"shard_map placement needs >= {S} devices, "
                          f"have {len(jax.devices())}")
@@ -571,9 +576,25 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
         return jax.jit(step)
 
     # -- multi-device ring: shard_map, one stage per device ------------------
+    # XLA moves values to host memory only outside conditionals, and each
+    # device picks its stage with ``lax.switch``: the crossing payloads hop
+    # after the switch (every device hops every transit slot), and a spill
+    # evicted *within* a stage cannot leave HBM on this placement.
     def build_shard_map():
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
+        if jax.devices()[0].platform == "tpu":   # where the hop is real
+            inner = [e for e in an.spills
+                     if e.reason == "evicted"
+                     and an.stage_of[e.src] == an.stage_of[e.dst]]
+            if inner:
+                raise ValueError(
+                    f"shard_map placement cannot move spills evicted "
+                    f"inside a stage to host memory "
+                    f"({', '.join(f'{e.src}->{e.dst}' for e in inner)}); "
+                    f"cut the stages at those edges or use 'interleave'")
+        ring_fns, _ = _make_stage_fns(g, an, names, crossing,
+                                      lambda x: x, enc)
         mesh = Mesh(np.array(jax.devices()[:S]), ("stage",))
         perm = [(i, (i + 1) % S) for i in range(S)]
 
@@ -588,12 +609,13 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
 
                 def branch(jj):
                     def f(params, x_t, reads):
-                        prod, y = stage_fns[jj](
+                        prod, y = ring_fns[jj](
                             params, x_t if jj == 0 else None, reads)
                         return fill_zeros(prod), y
                     return f
                 produced, y = jax.lax.switch(
                     j, [branch(jj) for jj in range(S)], params, x_t, reads)
+                produced = jax.tree.map(hop, produced)
                 new_carry = {}
                 for e in crossing:
                     i_prod = an.stage_of[e[0]]
@@ -612,8 +634,8 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
             ys = jnp.where(j == S - 1, ys, 0.0)
             return jax.lax.psum(ys, "stage")
 
-        smap = _shard_map_compat(body, mesh, in_specs=(P(), P()),
-                                 out_specs=P())
+        smap = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=P(), check_vma=False)
 
         def step(params, xs):
             _check_stream_shape(xs)
@@ -682,15 +704,6 @@ def _stage_call(stage_fn, params, x, reads):
     jitted signature only contains arrays."""
     prod, y = stage_fn(params, x, reads)
     return {e: p for e, p in prod.items() if p is not None}, y
-
-
-def _shard_map_compat(f, mesh, *, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):                       # jax >= 0.7
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
 
 # =============================================================================

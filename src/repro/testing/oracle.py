@@ -110,7 +110,19 @@ def _first_divergence(ref, other, x) -> str:
     return "no intermediate divergence found (outputs differ only)"
 
 
-def _lossless_twin(plan):
+#: per-BFP8-stream share of the reference's L2 norm a lossy plan may drift
+BFP8_REL_ERR_PER_STREAM = 0.25
+
+
+def bfp8_l2_bound(ref_norm: float, n_lossy: int,
+                  rel_err_per_lossy: float = BFP8_REL_ERR_PER_STREAM
+                  ) -> float:
+    """The ``bfp8_bounded`` limit on ``||y - y_ref||_2`` for a plan with
+    ``n_lossy`` BFP8-evicted streams, given ``||y_ref||_2``."""
+    return rel_err_per_lossy * n_lossy * ref_norm + 1e-3
+
+
+def lossless_twin(plan):
     """The same plan with every stream codec forced lossless: eviction
     decisions survive, only the lossy compression is removed — exactly
     the plan under which SMOF's eviction must be semantics-preserving."""
@@ -123,7 +135,8 @@ def _lossless_twin(plan):
 
 
 def check_case(case: FuzzCase, *, resident_limit: int = 2,
-               rel_err_per_lossy: float = 0.25) -> CaseReport:
+               rel_err_per_lossy: float = BFP8_REL_ERR_PER_STREAM
+               ) -> CaseReport:
     """Run every oracle over ``case``; raises :class:`OracleViolation` on
     the first failure, returns a :class:`CaseReport` when all pass."""
     import jax.numpy as jnp
@@ -169,7 +182,7 @@ def check_case(case: FuzzCase, *, resident_limit: int = 2,
 
     # -- lossless_exact ------------------------------------------------------
     lossy = [s for s in plan.streams if s.evicted and s.codec == "bfp8"]
-    twin = _lossless_twin(plan) if lossy else plan
+    twin = lossless_twin(plan) if lossy else plan
     if lossy:
         c_tw_staged = repro.compile(repro.CompileSpec(
             mode="staged", plan=twin, **base))
@@ -211,8 +224,8 @@ def check_case(case: FuzzCase, *, resident_limit: int = 2,
                     "bfp8_bounded", f"non-finite staged output on frame {b} "
                     f"({len(lossy)} BFP8 stream(s))")
             err = float(np.linalg.norm(y - ref_ys[b]))
-            bound = (rel_err_per_lossy * len(lossy)
-                     * float(np.linalg.norm(ref_ys[b])) + 1e-3)
+            bound = bfp8_l2_bound(float(np.linalg.norm(ref_ys[b])),
+                                  len(lossy), rel_err_per_lossy)
             if err > bound:
                 raise OracleViolation(
                     "bfp8_bounded",

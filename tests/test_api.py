@@ -233,6 +233,20 @@ class TestSaveLoad:
         np.testing.assert_array_equal(np.asarray(back.run(x)),
                                       np.asarray(c.run(x)))
 
+    def test_auto_placement_artifact_reloads_interleaved(self, tmp_path):
+        # older artifacts saved placement="auto"; it reloads as the
+        # one-device scan, never as the multi-device ring
+        g = build_unet_exec(positions=32, levels=2)
+        c = repro.compile(_spec(g, mode="pipelined", microbatches=2))
+        path = c.save(tmp_path / "auto.smof.json")
+        d = json.loads(path.read_text())
+        assert d["placement"] == "interleave"
+        d["placement"] = "auto"
+        path.write_text(json.dumps(d))
+        back = Compiled.load(path)
+        assert back.spec.placement == "interleave"
+        assert back.executor.placement == "interleave"
+
     def test_newer_artifact_schema_rejected(self, tmp_path):
         c = repro.compile(_spec("unet_exec", mode="staged"))
         path = c.save(tmp_path / "a.json")

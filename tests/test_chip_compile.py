@@ -1,0 +1,138 @@
+"""Compile-only rehearsal of the main path's Pallas kernels for a TPU v5e.
+
+Nothing runs here: every kernel is lowered and compiled by the TPU compiler
+for a described ``v5e:2x2`` topology (no chip attached), at the widths
+``chip_smoke.py`` drives — the UNet frame of 368x480 positions and its
+64..1024 channel ladder.  Interpret mode cannot show what Mosaic refuses
+(unsupported reshapes, value-level dynamic slices, unaligned tiles); this
+file does, at no chip time.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library, and under
+pytest-xdist every worker imports this file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import bfp8, streaming_conv as SC
+from repro.kernels.streamed_matmul import streamed_matmul_padded
+
+POSITIONS = 368 * 480        # the UNet frame, flattened
+BLOCK = 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape_of(topo):
+    """``shape_of(shape, dtype)`` -> a ShapeDtypeStruct on chip 0."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    # compiles for a described chip cannot be read back from the
+    # persistent cache, so keep them out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    yield make
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _payload(shape_of, m, c):
+    return (shape_of((m, c), jnp.int8), shape_of((m, c // BLOCK), jnp.int8))
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (m, cin, cout): the first encoder level at full resolution, and the
+# deepest level (four halvings) at the widest channels
+CONV_CASES = [(POSITIONS, 64, 128), (POSITIONS // 16, 512, 1024)]
+
+
+@pytest.mark.parametrize("m,cin,cout", CONV_CASES)
+@pytest.mark.parametrize("variant", ["plain", "egress", "ingress", "both"])
+def test_conv2d_compiles(shape_of, variant, m, cin, cout):
+    ingress = variant in ("ingress", "both")
+    encode = variant in ("egress", "both")
+    w = shape_of((cin, cout))
+    if ingress:
+        _compile(lambda man, exp, w: SC.conv2d(None, w, payload=(man, exp),
+                                                encode=encode),
+                 *_payload(shape_of, m, cin), w)
+    else:
+        _compile(lambda x, w: SC.conv2d(x, w, encode=encode),
+                 shape_of((m, cin)), w)
+
+
+def test_conv2d_normalised_tile_compiles(shape_of):
+    """A tile request Mosaic would refuse (bm=5, bc=7) is normalised to one
+    it accepts (8 rows, the full 128-lane-multiple channel block)."""
+    _compile(lambda x, w: SC.conv2d(x, w, bm=5, bc=7),
+             shape_of((4096, 64)), shape_of((64, 256)))
+
+
+# dwconv keeps its input un-blocked in VMEM (halo reads), so it is
+# rehearsed on a temporal stripe that fits there
+DW_M, DW_C = 4096, 128
+
+
+@pytest.mark.parametrize("variant", ["plain", "egress", "ingress"])
+def test_dwconv_compiles(shape_of, variant):
+    w = shape_of((3, DW_C))
+    if variant == "ingress":
+        _compile(lambda man, exp, w: SC.dwconv(None, w, payload=(man, exp)),
+                 *_payload(shape_of, DW_M, DW_C), w)
+    else:
+        _compile(lambda x, w: SC.dwconv(x, w, encode=variant == "egress"),
+                 shape_of((DW_M, DW_C)), w)
+
+
+def test_pool_egress_compiles(shape_of):
+    _compile(lambda x: SC.pool(x, POSITIONS // 2, encode=True),
+             shape_of((POSITIONS, 64)))
+
+
+@pytest.mark.parametrize("variant", ["egress", "ingress"])
+def test_act_relu_compiles(shape_of, variant):
+    m, c = POSITIONS, 64
+    if variant == "ingress":
+        _compile(lambda man, exp: SC.act_relu(None, c=c, payload=(man, exp)),
+                 *_payload(shape_of, m, c))
+    else:
+        _compile(lambda x: SC.act_relu(x, encode=True), shape_of((m, c)))
+
+
+# the skip three halvings down: 22080 rows, not a multiple of the stripe
+@pytest.mark.parametrize("c", [64, 512])
+def test_bfp8_quant_compiles(shape_of, c):
+    _compile(lambda x: bfp8.bfp8_quant(x, block=BLOCK),
+             shape_of((POSITIONS // 8, c)))
+
+
+@pytest.mark.parametrize("c", [64, 512])
+def test_bfp8_dequant_compiles(shape_of, c):
+    _compile(lambda man, exp: bfp8.bfp8_dequant(man, exp, block=BLOCK),
+             *_payload(shape_of, POSITIONS // 8, c))
+
+
+def test_streamed_matmul_padded_compiles(shape_of):
+    _compile(lambda x, w: streamed_matmul_padded(x, w, static_fraction=0.5),
+             shape_of((POSITIONS // 16, 512)), shape_of((512, 1024)))

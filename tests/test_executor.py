@@ -20,8 +20,10 @@ from repro.core.compression import bfp8_decode, bfp8_encode, bfp8_ratio
 from repro.core.plan import ExecutionPlan, LayerPlan, StreamPlan
 from repro.core.resources import Device
 from repro.runtime.executor import (LoweredPipeline, SpillReport,
-                                    _bfp8_roundtrip, init_params, lower_plan,
-                                    reference_pipeline)
+                                    _bfp8_roundtrip, _make_offchip_hop,
+                                    analyze_plan, init_params, lower_plan,
+                                    reference_pipeline, resolve_kernel_mode,
+                                    vertex_body)
 
 TINY = Device("tiny", compute_units=4096, onchip_bits=300_000,
               offchip_gbps=64.0, freq_mhz=500.0, reconfig_s=0.0)
@@ -209,3 +211,121 @@ class TestLoweringErrors:
         assert isinstance(ref, LoweredPipeline)
         x = jnp.zeros((32, 32), jnp.float32)
         assert ref(x).shape == (32 * 32,)
+
+
+class _Memory:
+    def __init__(self, kind):
+        self.kind = kind
+
+
+class _FakeTpu:
+    """A TPU device as ``jax.devices()`` would list it, with chosen memory
+    kinds — steers the hop's TPU branch on a CPU host."""
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+    def __init__(self, kinds):
+        self._kinds = kinds
+
+    def addressable_memories(self):
+        return [_Memory(k) for k in self._kinds]
+
+
+class TestDevicePath:
+    """What decides where a run's work lands: the off-chip hop, the kernel
+    mode on a TPU, the placement, and which body runs each vertex."""
+
+    def test_offchip_hop_is_identity_off_tpu(self):
+        assert jax.devices()[0].platform == "cpu"
+        x = jnp.arange(6.0)
+        assert _make_offchip_hop()(x) is x
+
+    def test_offchip_hop_raises_on_tpu_without_host_memory(self,
+                                                           monkeypatch):
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a, **k: [_FakeTpu(["device"])])
+        with pytest.raises(RuntimeError, match="pinned_host"):
+            _make_offchip_hop()
+
+    def test_offchip_hop_on_tpu_round_trips_values(self, monkeypatch):
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda *a, **k: [_FakeTpu(["device", "pinned_host"])])
+        hop = _make_offchip_hop()
+        x = jnp.arange(12.0).reshape(3, 4)
+        np.testing.assert_array_equal(np.asarray(jax.jit(hop)(x)),
+                                      np.asarray(x))
+
+    def test_interpret_refused_for_pallas_on_tpu(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="interpret"):
+            resolve_kernel_mode("pallas", True)
+        assert resolve_kernel_mode("pallas", None) == (True, False)
+        assert resolve_kernel_mode("auto", None) == (True, False)
+        # the reference bodies never interpret anything: nothing to refuse
+        assert resolve_kernel_mode("reference", True) == (False, True)
+
+    def test_placement_is_explicit(self):
+        from repro.api import CompileSpec
+        from repro.runtime.streamer import lower_plan_pipelined
+        g = build_unet_exec()
+        plan, _ = _dse_plan(g)
+        assert CompileSpec(model=g).placement == "interleave"
+        sx = lower_plan_pipelined(g, plan, microbatches=2,
+                                  kernel_mode="reference")
+        assert sx.placement == "interleave"
+        with pytest.raises(ValueError, match="placement"):
+            lower_plan_pipelined(g, plan, microbatches=2,
+                                 kernel_mode="reference", placement="auto")
+
+    def test_vertex_body_names_plain_dot_fallback(self):
+        """A fully streamed weight of at most 128 rows has no dynamic
+        region and runs as a plain dot; a wider one runs the Pallas
+        fragmentation kernel."""
+        g = build_unet_exec(levels=4)
+        plan = ExecutionPlan(
+            model=g.name, device="tiny", n_stages=1,
+            layers={n: LayerPlan(name=n, weight_static_fraction=0.0)
+                    for n in g.topo()},
+            streams=[StreamPlan(e.src, e.dst) for e in g.edges()],
+            topo_order=g.topo())
+        an = analyze_plan(g, plan, use_pallas=True, interpret=True)
+        seen = set()
+        for n in an.topo:
+            v = g.vertex(n)
+            body = vertex_body(g, n, an)
+            if v.kind in ("conv", "deconv"):
+                wide = v.meta["exec"]["cin"] > 128
+                assert body == ("pallas" if wide else "reference"), n
+                seen.add(wide)
+            elif v.kind in ("act", "pool"):
+                assert body == "pallas", n
+            else:
+                assert body == "reference", n
+        assert seen == {True, False}
+        an_ref = analyze_plan(g, plan, use_pallas=False, interpret=True)
+        assert {vertex_body(g, n, an_ref) for n in an.topo} == {"reference"}
+
+
+class TestCompileCache:
+    def test_env_var_wins(self, monkeypatch):
+        from repro.compile_cache import ENV_VAR, enable_compile_cache
+        monkeypatch.setenv(ENV_VAR, "cache-from-env")
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == "cache-from-env"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_repo_directory(self, monkeypatch):
+        import pathlib
+        from repro.compile_cache import (DEFAULT_DIR, ENV_VAR,
+                                         enable_compile_cache)
+        monkeypatch.delenv(ENV_VAR, raising=False)
+        repo = pathlib.Path(__file__).resolve().parent.parent
+        assert DEFAULT_DIR == repo / ".jax_cache"
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            assert enable_compile_cache() == str(DEFAULT_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

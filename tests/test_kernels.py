@@ -7,7 +7,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
-from repro.kernels.bfp8 import bfp8_dequant, bfp8_quant
+from repro.kernels.bfp8 import (bfp8_dequant, bfp8_dequant_values,
+                                bfp8_quant, bfp8_quant_values)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import evict_decode, evict_encode, fragmented_matmul
 from repro.kernels.streamed_matmul import streamed_matmul, vmem_bytes
@@ -121,6 +122,41 @@ class TestBFP8Kernel:
         want = ref.bfp8_dequant_ref(man_r, exp_r, block=block)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-6)
+
+    @pytest.mark.parametrize("R,C", [(1, 32), (7, 64), (13, 96), (45, 160),
+                                     (3, 1024)])
+    @pytest.mark.parametrize("magnitude", [1e-30, 1e-3, 1.0, 1e30])
+    def test_value_codec_bitwise_equals_ref(self, R, C, magnitude):
+        """The reshape-free codec math is bit for bit the reshaped
+        reference on odd shapes: all-zero blocks, exact powers of two and
+        magnitudes at both ends of the f32 range included."""
+        x = np.asarray(jax.random.normal(jax.random.PRNGKey(R * C), (R, C),
+                                         jnp.float32)) * magnitude
+        x[0, :32] = 0.0
+        if C >= 64:
+            x[-1, 32:64] = 2.0 ** np.arange(-10, 22)
+        quant = jax.jit(lambda a: bfp8_quant_values(a, block=32))
+        man, exp = quant(x)
+        man_r, exp_r = jax.jit(lambda a: ref.bfp8_quant_ref(a, block=32))(x)
+        np.testing.assert_array_equal(np.asarray(man), np.asarray(man_r))
+        np.testing.assert_array_equal(np.asarray(exp), np.asarray(exp_r))
+        out = jax.jit(lambda m, e: bfp8_dequant_values(m, e, block=32))(
+            man_r, exp_r)
+        want = jax.jit(lambda m, e: ref.bfp8_dequant_ref(m, e, block=32))(
+            man_r, exp_r)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+    @pytest.mark.parametrize("R", [1, 255, 300, 513])
+    def test_stripe_kernels_pad_rows(self, R):
+        """Row counts that are not a stripe multiple pad and slice back."""
+        x = jax.random.normal(jax.random.PRNGKey(R), (R, 64), jnp.float32)
+        man, exp = bfp8_quant(x, interpret=True)
+        man_r, exp_r = ref.bfp8_quant_ref(x)
+        np.testing.assert_array_equal(np.asarray(man), np.asarray(man_r))
+        np.testing.assert_array_equal(np.asarray(exp), np.asarray(exp_r))
+        np.testing.assert_array_equal(
+            np.asarray(bfp8_dequant(man, exp, interpret=True)),
+            np.asarray(ref.bfp8_dequant_ref(man_r, exp_r)))
 
     @given(st.integers(0, 2 ** 31 - 1))
     @settings(max_examples=20, deadline=None)
@@ -285,6 +321,16 @@ class TestKernelConformanceMatrix:
             tiled = _call_kernel(kind, x, w, extra, bm=bm, bc=bc)
             np.testing.assert_array_equal(np.asarray(base),
                                           np.asarray(tiled))
+
+    def test_tile_requests_normalise_to_mosaic_tiles(self):
+        """A row block is whole 8-row sublane tiles and a channel block
+        whole 128-lane tiles, or the full axis; 0 is the default."""
+        assert SC._tile(45, 5, SC.DEFAULT_BM, SC.BM_ALIGN) == 8
+        assert SC._tile(45, 13, SC.DEFAULT_BM, SC.BM_ALIGN) == 16
+        assert SC._tile(45, 0, SC.DEFAULT_BM, SC.BM_ALIGN) == 45
+        assert SC._tile(56, 7, SC.DEFAULT_BC, SC.BC_ALIGN) == 56
+        assert SC._tile(300, 40, SC.DEFAULT_BC, SC.BC_ALIGN) == 128
+        assert SC._tile(300, 0, SC.DEFAULT_BC, SC.BC_ALIGN) == 128
 
     def test_fused_equals_unfused_same_quant_blocks(self):
         """decode->conv->encode fused into one pallas_call is bitwise the

@@ -491,7 +491,8 @@ class TestShardMapPlacement:
             xs = jax.random.normal(jax.random.PRNGKey(1), (B, 64, 32),
                                    jnp.float32)
             sx = lower_plan_pipelined(g, plan, microbatches=B,
-                                      kernel_mode="reference")
+                                      kernel_mode="reference",
+                                      placement="shard_map")
             assert sx.placement == "shard_map", sx.placement
             low = lower_plan(g, plan, kernel_mode="reference")
             want = np.stack([np.asarray(low(xs[b])) for b in range(B)])
