@@ -58,7 +58,7 @@ from .core.plan import ExecutionPlan, PLAN_SCHEMA_VERSION, plan_from_dse
 from .core.resources import ALL_DEVICES, Device, get_device
 from .memory import POLICIES, ChannelConfig
 from .obs.metrics import MetricsRegistry
-from .obs.trace import NULL_RECORDER, ObsConfig, TraceRecorder
+from .obs.trace import NULL_RECORDER, ObsConfig, TraceRecorder, host_span
 
 MODES = ("reference", "staged", "pipelined")
 STRATEGIES = ("dse", "autotune", "manual-plan")
@@ -216,35 +216,40 @@ def compile(spec: CompileSpec) -> "Compiled":
     run, serve, report, and persist itself.  Numerics are bit-identical to
     calling the underlying ``lower_plan`` / ``lower_plan_pipelined``
     directly with the same plan and seed.
+
+    The search and the lowering are the host spans ``smof.compile.search``
+    and ``smof.compile.lower``; XLA compiles the step at its first call.
     """
     spec.validate()
     g = _resolve_graph(spec)
     # one registry per artifact: the autotune search, traced runs and any
     # server built from this compile all land on the same scrape surface
     registry = MetricsRegistry()
-    plan, autotune_result = build_plan(spec, g, metrics=registry)
+    with host_span("compile.search"):
+        plan, autotune_result = build_plan(spec, g, metrics=registry)
     km = spec.resolved_kernel_mode()
 
-    if spec.mode == "reference":
-        from .runtime.executor import reference_pipeline
-        executor = reference_pipeline(g, seed=spec.seed)
-    elif spec.mode == "staged":
-        from .runtime.executor import lower_plan
-        executor = lower_plan(g, plan, kernel_mode=km, seed=spec.seed,
-                              interpret=spec.interpret)
-    else:                                     # "pipelined"
-        from .runtime.streamer import lower_plan_pipelined
-        B = spec.microbatches
-        if autotune_result is not None:       # serve at the measured depth
-            B = autotune_result.microbatches
-        try:
-            dev = _resolve_device(spec)
-        except (KeyError, ValueError):
-            dev = None
-        executor = lower_plan_pipelined(
-            g, plan, microbatches=B, kernel_mode=km, seed=spec.seed,
-            interpret=spec.interpret, placement=spec.placement,
-            channel=spec.channel, device=dev)
+    with host_span("compile.lower"):
+        if spec.mode == "reference":
+            from .runtime.executor import reference_pipeline
+            executor = reference_pipeline(g, seed=spec.seed)
+        elif spec.mode == "staged":
+            from .runtime.executor import lower_plan
+            executor = lower_plan(g, plan, kernel_mode=km, seed=spec.seed,
+                                  interpret=spec.interpret)
+        else:                                 # "pipelined"
+            from .runtime.streamer import lower_plan_pipelined
+            B = spec.microbatches
+            if autotune_result is not None:   # serve at the measured depth
+                B = autotune_result.microbatches
+            try:
+                dev = _resolve_device(spec)
+            except (KeyError, ValueError):
+                dev = None
+            executor = lower_plan_pipelined(
+                g, plan, microbatches=B, kernel_mode=km, seed=spec.seed,
+                interpret=spec.interpret, placement=spec.placement,
+                channel=spec.channel, device=dev)
 
     return Compiled(spec=spec, graph=g, device=_device_name(spec, plan),
                     plan=plan, executor=executor,
@@ -297,14 +302,17 @@ class Compiled:
         return self.run(x)
 
     def run(self, x):
+        """Dispatch one call of the executor; the host span ``smof.run``
+        covers the dispatch, not the device's work."""
         import jax.numpy as jnp
-        x = jnp.asarray(x)
-        if self.mode == "pipelined" and x.ndim == 2:
-            # single-frame convenience: broadcast through the stream,
-            # every slot computes the same frame — return one output
-            B = self.executor.microbatches
-            return self.executor(jnp.broadcast_to(x, (B,) + x.shape))[0]
-        return self.executor(x)
+        with host_span("run"):
+            x = jnp.asarray(x)
+            if self.mode == "pipelined" and x.ndim == 2:
+                # single-frame convenience: broadcast through the stream,
+                # every slot computes the same frame — return one output
+                B = self.executor.microbatches
+                return self.executor(jnp.broadcast_to(x, (B,) + x.shape))[0]
+            return self.executor(x)
 
     def input_shape(self) -> tuple[int, int]:
         return exec_input_shape(self.graph)

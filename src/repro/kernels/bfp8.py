@@ -116,7 +116,7 @@ def bfp8_quant(x: jax.Array, *, block: int = 32, rows: int = 256,
                    pl.BlockSpec((rows, C // block), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((Rp, C), jnp.int8),
                    jax.ShapeDtypeStruct((Rp, C // block), jnp.int8)],
-        interpret=interpret,
+        interpret=interpret, name="smof_bfp8_quant",
     )(_pad_rows(x, Rp))
     return man[:R], exp[:R]
 
@@ -133,6 +133,6 @@ def bfp8_dequant(man: jax.Array, exp: jax.Array, *, block: int = 32,
                   pl.BlockSpec((rows, C // block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, C), dtype),
-        interpret=interpret,
+        interpret=interpret, name="smof_bfp8_dequant",
     )(_pad_rows(man, Rp), _pad_rows(exp, Rp))
     return out[:R]

@@ -78,7 +78,7 @@ def streamed_matmul(x: jax.Array, w_static: jax.Array, w_dyn: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         # fp32 accumulator tile lives in VMEM across the k loop
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="smof_streamed_matmul",
     )(x_static, x_dyn, w_static, w_dyn)
 
 

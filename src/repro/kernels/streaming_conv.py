@@ -69,6 +69,14 @@ def _tile(n: int, b: int, default: int, align: int) -> int:
     return min(b, n) if n > 0 else b
 
 
+def _name(kind: str, payload, encode: bool) -> str:
+    """The ``pallas_call`` name of a kernel: its kind and the codec it
+    fuses, e.g. ``smof_conv``, ``smof_act_bfp8out`` or
+    ``smof_pool_bfp8in_bfp8out``; the compiled custom call carries it."""
+    return (f"smof_{kind}" + ("_bfp8in" if payload is not None else "")
+            + ("_bfp8out" if encode else ""))
+
+
 def _pad_payload(payload, mp: int):
     man, exp = payload
     return _pad_rows(man, mp), _pad_rows(exp, mp)
@@ -139,7 +147,7 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
                           pl.BlockSpec((cin, bc), lambda i, j: (0, j))],
                 out_specs=pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
                 out_shape=jax.ShapeDtypeStruct((mp, npad), jnp.float32),
-                interpret=interpret,
+                interpret=interpret, name=_name("conv", payload, encode),
             )(_pad_rows(x, mp), wp)
         else:
             y = pl.pallas_call(
@@ -151,7 +159,7 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
                           pl.BlockSpec((cin, bc), lambda i, j: (0, j))],
                 out_specs=pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
                 out_shape=jax.ShapeDtypeStruct((mp, npad), jnp.float32),
-                interpret=interpret,
+                interpret=interpret, name=_name("conv", payload, encode),
             )(*_pad_payload(payload, mp), wp)
         return y[:m, :n]
 
@@ -172,6 +180,7 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
             in_specs=[pl.BlockSpec((bm, cin), lambda i: (i, 0)),
                       pl.BlockSpec((cin, npad), lambda i: (0, 0))],
             out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+            name=_name("conv", payload, encode),
         )(_pad_rows(x, mp), wp)
     else:
         y, man_o, exp_o = pl.pallas_call(
@@ -181,6 +190,7 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
                       pl.BlockSpec((bm, c_pad // block), lambda i: (i, 0)),
                       pl.BlockSpec((cin, npad), lambda i: (0, 0))],
             out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+            name=_name("conv", payload, encode),
         )(*_pad_payload(payload, mp), wp)
     return y[:m, :n], (man_o[:m], exp_o[:m])
 
@@ -271,7 +281,8 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
                 grid=grid, in_specs=in_specs,
                 out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
                 out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
-                interpret=interpret)(xp, w)
+                interpret=interpret,
+                name=_name("dwconv", payload, encode))(xp, w)
             return y[:m]
         y, man_o, exp_o = pl.pallas_call(
             functools.partial(_dwconv_enc_kernel, block=block, bm=bm,
@@ -283,7 +294,8 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
             out_shape=[jax.ShapeDtypeStruct((mp, c), jnp.float32),
                        jax.ShapeDtypeStruct((mp, cq), jnp.int8),
                        jax.ShapeDtypeStruct((mp, cq // block), jnp.int8)],
-            interpret=interpret)(xp, w)
+            interpret=interpret,
+            name=_name("dwconv", payload, encode))(xp, w)
         return y[:m], (man_o[:m], exp_o[:m])
 
     # ingress-fused: the payload stays un-blocked too (the decode is
@@ -302,7 +314,8 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
             grid=grid, in_specs=in_specs,
             out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
-            scratch_shapes=scratch, interpret=interpret)(man, exp, w)
+            scratch_shapes=scratch, interpret=interpret,
+            name=_name("dwconv", payload, encode))(man, exp, w)
         return y[:m]
     y, man_o, exp_o = pl.pallas_call(
         functools.partial(_dwconv_dec_enc_kernel, block=block, c=c, bm=bm,
@@ -314,7 +327,8 @@ def dwconv(x, w, *, payload=None, encode=False, block: int = 32,
         out_shape=[jax.ShapeDtypeStruct((mp, c), jnp.float32),
                    jax.ShapeDtypeStruct((mp, cq), jnp.int8),
                    jax.ShapeDtypeStruct((mp, cq // block), jnp.int8)],
-        interpret=interpret)(man, exp, w)
+        interpret=interpret,
+        name=_name("dwconv", payload, encode))(man, exp, w)
     return y[:m], (man_o[:m], exp_o[:m])
 
 
@@ -392,7 +406,7 @@ def pool(x, m_out: int, *, c: int | None = None, payload=None, encode=False,
             grid=grid, in_specs=in_specs,
             out_specs=pl.BlockSpec((bo, c), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((mop, c), jnp.float32),
-            interpret=interpret)(*args)
+            interpret=interpret, name=_name("pool", payload, encode))(*args)
         return y[:m_out]
     y, man_o, exp_o = pl.pallas_call(
         functools.partial(kern_enc, block=block, k=k, **dec_kw),
@@ -403,7 +417,7 @@ def pool(x, m_out: int, *, c: int | None = None, payload=None, encode=False,
         out_shape=[jax.ShapeDtypeStruct((mop, c), jnp.float32),
                    jax.ShapeDtypeStruct((mop, cq), jnp.int8),
                    jax.ShapeDtypeStruct((mop, cq // block), jnp.int8)],
-        interpret=interpret)(*args)
+        interpret=interpret, name=_name("pool", payload, encode))(*args)
     return y[:m_out], (man_o[:m_out], exp_o[:m_out])
 
 
@@ -461,7 +475,8 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
                 _act_kernel, grid=grid, in_specs=in_specs,
                 out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
                 out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
-                interpret=interpret)(*args)
+                interpret=interpret,
+                name=_name("act", payload, encode))(*args)
             return y[:m]
         kern = functools.partial(_act_enc_kernel, block=block)
     else:
@@ -474,7 +489,8 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
                 grid=grid, in_specs=in_specs,
                 out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
                 out_shape=jax.ShapeDtypeStruct((mp, c), jnp.float32),
-                interpret=interpret)(*args)
+                interpret=interpret,
+                name=_name("act", payload, encode))(*args)
             return y[:m]
         kern = functools.partial(_act_dec_enc_kernel, block=block, c=c)
     y, man_o, exp_o = pl.pallas_call(
@@ -485,7 +501,7 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
         out_shape=[jax.ShapeDtypeStruct((mp, c), jnp.float32),
                    jax.ShapeDtypeStruct((mp, cq), jnp.int8),
                    jax.ShapeDtypeStruct((mp, cq // block), jnp.int8)],
-        interpret=interpret)(*args)
+        interpret=interpret, name=_name("act", payload, encode))(*args)
     return y[:m], (man_o[:m], exp_o[:m])
 
 

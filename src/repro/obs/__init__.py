@@ -7,7 +7,9 @@ Dependency-free layers so anything in the repo can import it:
   is off), :class:`TraceRecorder` (in-memory spans/counters with a
   Chrome trace-event / Perfetto JSON exporter), ``validate_chrome_trace``
   (schema check for emitted files) and :class:`LatencyHistogram`
-  (log-bucketed per-request latencies for serving).
+  (log-bucketed per-request latencies for serving); ``scope`` and
+  ``host_span``, the ``smof.*`` names of device ops and host intervals
+  in a ``jax.profiler`` trace.
 * :mod:`repro.obs.stream` — :class:`StreamTracer`, the per-tick narrator
   for the pipelined streamer (tick/stage spans by 1F1B phase, queue
   occupancy through the bounded rings, spill byte counters), plus
@@ -37,7 +39,7 @@ from .modelcheck import (ContentionCheck, ModelCheck, QueueDepthCheck,
 from .slo import BREACH, PASS, WARN, SloCheck, SloConfig, SloEvaluator, SloReport
 from .stream import StreamTracer, emit_spill_counters
 from .trace import (NULL_RECORDER, LatencyHistogram, NullRecorder, ObsConfig,
-                    TraceRecorder, validate_chrome_trace)
+                    TraceRecorder, host_span, scope, validate_chrome_trace)
 
 __all__ = [
     "ObsConfig",
@@ -46,6 +48,8 @@ __all__ = [
     "TraceRecorder",
     "LatencyHistogram",
     "validate_chrome_trace",
+    "scope",
+    "host_span",
     "StreamTracer",
     "emit_spill_counters",
     "ModelCheck",

@@ -20,8 +20,16 @@ Design rules (the ISSUE 6 contract):
 * timestamps are kept in seconds internally and converted to the Chrome
   format's microseconds only at export.
 
+Two helpers name the program's work for a ``jax.profiler`` trace, where
+host and device share one clock: :func:`scope` names the device ops a
+traced function emits (a ``jax.named_scope``, carried in the compiled
+HLO's ``op_name`` metadata, which XLA does not read when it optimises),
+and :func:`host_span` names a host interval (a ``TraceAnnotation``, a
+no-op unless the profiler is on).  Every name starts with ``smof.``.
+
 See ``docs/OBSERVABILITY.md`` for the span/counter taxonomy emitted by
-the streamer, the serving engine, and the autotuner.
+the streamer, the serving engine, and the autotuner, and for the device
+scopes and host spans.
 """
 from __future__ import annotations
 
@@ -35,8 +43,30 @@ from typing import Any, Callable
 
 __all__ = [
     "ObsConfig", "NullRecorder", "TraceRecorder", "NULL_RECORDER",
-    "LatencyHistogram", "validate_chrome_trace",
+    "LatencyHistogram", "validate_chrome_trace", "scope", "host_span",
 ]
+
+#: the first characters of every device scope and host span the program
+#: names, so a reader of a trace can tell the program's names from others
+PREFIX = "smof."
+
+
+def scope(kind: str, name: str | None = None):
+    """A ``jax.named_scope`` for the device ops traced inside it:
+    ``smof.<kind>:<name>``, or ``smof.<kind>`` without a name.  It is one
+    path component of the ops' ``op_name``, so a ``/`` in a vertex name
+    reads as ``|``."""
+    import jax
+    label = f"{PREFIX}{kind}" if name is None else f"{PREFIX}{kind}:{name}"
+    return jax.named_scope(label.replace("/", "|"))
+
+
+def host_span(name: str):
+    """A host interval ``smof.<name>`` on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation``, which records nothing unless a
+    profiler trace is being taken."""
+    import jax
+    return jax.profiler.TraceAnnotation(f"{PREFIX}{name}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,11 +179,13 @@ class TraceRecorder(NullRecorder):
              args: dict | None = None):
         """Measure a host-side interval; yields a mutable args dict so the
         body can attach results (e.g. a measured fps) before the span
-        closes."""
+        closes.  The interval is also a :func:`host_span` of the same
+        name, so it lands in a profiler trace taken meanwhile."""
         span_args = dict(args or {})
         t0 = self.now()
         try:
-            yield span_args
+            with host_span(name):
+                yield span_args
         finally:
             self.add_span(name, t0, self.now() - t0, track=track, cat=cat,
                           args=span_args)

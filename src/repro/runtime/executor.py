@@ -71,6 +71,7 @@ from ..kernels import streaming_conv as SC
 from ..kernels.bfp8 import bfp8_dequant, bfp8_quant
 from ..kernels.streamed_matmul import (_round_up, splits_weight,
                                       streamed_matmul_padded)
+from ..obs.trace import scope
 
 WEIGHT_KINDS = ("conv", "deconv", "matmul")
 TEMPORAL_KINDS = ("dwconv",)
@@ -504,6 +505,15 @@ def run_vertices(g: Graph, an: PlanAnalysis, names: list[str], params: dict,
     locks.  ``external(edge)`` resolves in-edges whose producer is outside
     ``names`` (the pipelined streamer's decoded crossing reads); pass
     ``None`` for a whole-graph run.  Returns ``(values, payloads)``.
+
+    The device ops it emits are named (``obs.trace.scope``):
+    ``smof.<kind>:<vertex>`` for a vertex's lowering, kernel, pads and
+    slices alike; ``smof.codec.enc:<vertex>`` and
+    ``smof.codec.dec:<src>-<dst>`` for the standalone BFP8 kernels, and
+    ``smof.codec:<src>-<dst>`` for a reference-mode spill round-trip.
+    The hop's copies to host memory and back carry no ``op_name`` once
+    compiled (XLA makes them from memory spaces); a trace shows them by
+    memory space ``S(5)``.
     """
     internal = set(names)
     values: dict[str, jax.Array] = {}
@@ -517,28 +527,36 @@ def run_vertices(g: Graph, an: PlanAnalysis, names: list[str], params: dict,
             if e.src not in internal:
                 ins.append(external(edge))
                 continue
+            link = f"{e.src}-{name}"
             if an.use_pallas and edge in an.bfp8_edges:
                 pay = jax.tree.map(hop, payloads[e.src])
                 if lv.fuse_in == edge:
                     payload_in = pay
                     ins.append(None)
                 else:
-                    ins.append(bfp8_spill_decode(
-                        pay, an.out_shape[e.src][1], use_pallas=True,
-                        interpret=an.interpret))
+                    with scope("codec.dec", link):
+                        ins.append(bfp8_spill_decode(
+                            pay, an.out_shape[e.src][1], use_pallas=True,
+                            interpret=an.interpret))
             else:
                 val = values[e.src]
                 fn = an.spill_fn.get(edge)
                 if fn is not None:
-                    val = hop(fn(val))
+                    with scope("codec", link):
+                        val = fn(val)
+                    val = hop(val)
                 ins.append(val)
-        y, pay = apply_vertex_fused(v, ins, params, x, an,
-                                    payload_in=payload_in,
-                                    want_payload=lv.fuse_out)
+        with scope(v.kind, name):
+            y, pay = apply_vertex_fused(v, ins, params, x, an,
+                                        payload_in=payload_in,
+                                        want_payload=lv.fuse_out)
         values[name] = y
         if lv.needs_payload:
-            payloads[name] = pay if pay is not None else bfp8_spill_encode(
-                y, use_pallas=True, interpret=an.interpret)
+            if pay is None:
+                with scope("codec.enc", name):
+                    pay = bfp8_spill_encode(y, use_pallas=True,
+                                            interpret=an.interpret)
+            payloads[name] = pay
     return values, payloads
 
 

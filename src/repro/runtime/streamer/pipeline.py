@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Callable
 
 import jax
@@ -43,7 +42,7 @@ from ...kernels.streamed_matmul import _round_up
 from ...memory import ChannelConfig, MemoryModel, build_memory_model
 from ...obs.modelcheck import ModelCheck, check_stream
 from ...obs.stream import StreamTracer
-from ...obs.trace import NULL_RECORDER
+from ...obs.trace import NULL_RECORDER, scope
 from ..executor import (BFP8_BLOCK, TEMPORAL_KINDS, PlanAnalysis, SpillReport,
                         _exec_spec, _make_offchip_hop, analyze_plan,
                         bfp8_spill_decode, bfp8_spill_encode, init_params,
@@ -216,7 +215,8 @@ def _make_stage_fns(g: Graph, an: PlanAnalysis, names: list[list[str]],
     """Per-stage callables with a uniform signature.
 
     ``hop`` moves the stage's evicted spills, and the payloads it hands to
-    later stages, off-chip and back.
+    later stages, off-chip and back.  A crossing payload's encode is the
+    scope ``smof.codec.enc:<src>``, as inside ``run_vertices``.
 
     ``fn_j(params, x, reads) -> (produced, y)`` where ``reads`` maps every
     crossing edge to its decoded value (stage ``j`` only touches the ones it
@@ -248,7 +248,8 @@ def _make_stage_fns(g: Graph, an: PlanAnalysis, names: list[list[str]],
                     # allowed) — bitwise what enc[e] would compute
                     pay = payloads.get(e[0]) if e in an.bfp8_edges else None
                     if pay is None:
-                        pay = enc[e](values[e[0]])
+                        with scope("codec.enc", e[0]):
+                            pay = enc[e](values[e[0]])
                     produced[e] = jax.tree.map(hop, pay)
                 else:
                     produced[e] = None       # filled with zeros by caller
@@ -346,8 +347,6 @@ class StreamingExecutor:
                              jnp.asarray(0, jnp.int32), xs)
         jax.block_until_ready(warm)
 
-        mem = self.report.memory
-        stalls = mem.stall_cycles if mem is not None else []
         carry = self._carry0()
         ys = []
         steady_durs: list[float] = []
@@ -363,12 +362,6 @@ class StreamingExecutor:
             tracer.tick(t, ts=ts, dur=dur)
             if sched.phase(t) == "steady":
                 steady_durs.append(dur)
-            # narrate where the channel model says compute waits on the
-            # shared port this tick (stall > 0 for an active stage)
-            for j in sched.active_stages(t):
-                if j < len(stalls) and stalls[j] > 0:
-                    recorder.instant(f"contention:stage{j}", ts,
-                                     track=f"stage{j}")
         acct = tracer.finish()
         if metrics is not None:
             self._record_metrics(metrics, acct)
@@ -417,22 +410,6 @@ class StreamingExecutor:
                 edge = f"{r.src}->{r.dst}"
                 spill.labels(edge=edge, direction="evict").inc(nbytes)
                 spill.labels(edge=edge, direction="restore").inc(nbytes)
-        mem = self.report.memory
-        if mem is not None:
-            stall = metrics.counter(
-                "smof_contention_stall_cycles_total",
-                "model cycles compute stalls on the shared off-chip "
-                "channel, by stage", ("stage",))
-            for j, c in enumerate(mem.stall_cycles):
-                # one frame's stall per microbatch the stage processed
-                if c > 0 and math.isfinite(c):
-                    stall.labels(stage=str(j)).inc(c * self.microbatches)
-            misses = mem.prefetch.deadline_misses
-            if misses:
-                metrics.counter(
-                    "smof_prefetch_deadline_misses_total",
-                    "weight prefetch slots that missed their stage-start "
-                    "deadline").inc(misses)
 
 
 def stage_weight_bits(g: Graph, an: PlanAnalysis) -> dict[int, int]:
@@ -544,35 +521,45 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
             lambda z, d=delay[e]: jnp.zeros((d,) + z.shape, z.dtype),
             zeros[e]) for e in crossing}
 
+    # Scopes: ``smof.tick`` (one tick's work), inside it ``smof.tick.read``
+    # (the tick's frame and crossing decodes) and ``smof.tick.carry`` (the
+    # delay-line shift); ``smof.emit`` (around the scan, so that only the
+    # scan's stacking of each tick's output into the ``(B, L)`` result and
+    # the final slice have it innermost).
     def tick_body(params, carry, t, xs):
-        x_t = jax.lax.dynamic_index_in_dim(
-            xs, jnp.clip(t, 0, B - 1), axis=0, keepdims=False)
-        reads = {e: dec[e](jax.tree.map(lambda b: b[-1], carry[e]))
-                 for e in crossing}
-        produced: dict = {}
-        y = jnp.zeros((out_len,), jnp.float32)
-        for j in range(S):
-            prod_j, y_j = stage_fns[j](params,
-                                       x_t if j == 0 else None, reads)
-            for e in crossing:
-                if prod_j[e] is not None:
-                    produced[e] = prod_j[e]
-            if j == S - 1:
-                y = y_j
-        new_carry = {
-            e: jax.tree.map(
-                lambda buf, new: jnp.concatenate(
-                    [new[None], buf[:-1]], axis=0),
-                carry[e], produced[e])
-            for e in crossing}
-        return new_carry, y
+        with scope("tick"):
+            with scope("tick.read"):
+                x_t = jax.lax.dynamic_index_in_dim(
+                    xs, jnp.clip(t, 0, B - 1), axis=0, keepdims=False)
+                reads = {e: dec[e](jax.tree.map(lambda b: b[-1], carry[e]))
+                         for e in crossing}
+            produced: dict = {}
+            y = jnp.zeros((out_len,), jnp.float32)
+            for j in range(S):
+                prod_j, y_j = stage_fns[j](params,
+                                           x_t if j == 0 else None, reads)
+                for e in crossing:
+                    if prod_j[e] is not None:
+                        produced[e] = prod_j[e]
+                if j == S - 1:
+                    y = y_j
+            with scope("tick.carry"):
+                new_carry = {
+                    e: jax.tree.map(
+                        lambda buf, new: jnp.concatenate(
+                            [new[None], buf[:-1]], axis=0),
+                        carry[e], produced[e])
+                    for e in crossing}
+            return new_carry, y
 
     def build_interleave():
         def step(params, xs):
             _check_stream_shape(xs)
-            _, ys = jax.lax.scan(lambda c, t: tick_body(params, c, t, xs),
-                                 make_carry0(), jnp.arange(sched.ticks))
-            return ys[S - 1:]
+            with scope("emit"):
+                _, ys = jax.lax.scan(
+                    lambda c, t: tick_body(params, c, t, xs),
+                    make_carry0(), jnp.arange(sched.ticks))
+                return ys[S - 1:]
         return jax.jit(step)
 
     # -- multi-device ring: shard_map, one stage per device ------------------
@@ -602,37 +589,45 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
             j = jax.lax.axis_index("stage")
 
             def tick(carry, t):
-                x_t = jax.lax.dynamic_index_in_dim(
-                    xs, jnp.clip(t, 0, B - 1), axis=0, keepdims=False)
-                reads = {e: dec[e](jax.tree.map(lambda b: b[0], carry[e]))
-                         for e in crossing}
+                with scope("tick"):
+                    with scope("tick.read"):
+                        x_t = jax.lax.dynamic_index_in_dim(
+                            xs, jnp.clip(t, 0, B - 1), axis=0,
+                            keepdims=False)
+                        reads = {e: dec[e](jax.tree.map(lambda b: b[0],
+                                                        carry[e]))
+                                 for e in crossing}
 
-                def branch(jj):
-                    def f(params, x_t, reads):
-                        prod, y = ring_fns[jj](
-                            params, x_t if jj == 0 else None, reads)
-                        return fill_zeros(prod), y
-                    return f
-                produced, y = jax.lax.switch(
-                    j, [branch(jj) for jj in range(S)], params, x_t, reads)
-                produced = jax.tree.map(hop, produced)
-                new_carry = {}
-                for e in crossing:
-                    i_prod = an.stage_of[e[0]]
-                    slot = jax.tree.map(
-                        lambda old, new: jnp.where(j == i_prod, new[None],
-                                                   old),
-                        carry[e], produced[e])
-                    new_carry[e] = jax.tree.map(
-                        lambda s: jax.lax.ppermute(s, "stage", perm), slot)
-                return new_carry, y
+                    def branch(jj):
+                        def f(params, x_t, reads):
+                            prod, y = ring_fns[jj](
+                                params, x_t if jj == 0 else None, reads)
+                            return fill_zeros(prod), y
+                        return f
+                    produced, y = jax.lax.switch(
+                        j, [branch(jj) for jj in range(S)], params, x_t,
+                        reads)
+                    produced = jax.tree.map(hop, produced)
+                    new_carry = {}
+                    with scope("tick.carry"):
+                        for e in crossing:
+                            i_prod = an.stage_of[e[0]]
+                            slot = jax.tree.map(
+                                lambda old, new: jnp.where(j == i_prod,
+                                                           new[None], old),
+                                carry[e], produced[e])
+                            new_carry[e] = jax.tree.map(
+                                lambda s: jax.lax.ppermute(s, "stage", perm),
+                                slot)
+                    return new_carry, y
 
             carry0 = {e: jax.tree.map(lambda z: z[None], zeros[e])
                       for e in crossing}
-            _, ys = jax.lax.scan(tick, carry0, jnp.arange(sched.ticks))
-            # only the last stage computed real outputs; share them
-            ys = jnp.where(j == S - 1, ys, 0.0)
-            return jax.lax.psum(ys, "stage")
+            with scope("emit"):
+                _, ys = jax.lax.scan(tick, carry0, jnp.arange(sched.ticks))
+                # only the last stage computed real outputs; share them
+                ys = jnp.where(j == S - 1, ys, 0.0)
+                return jax.lax.psum(ys, "stage")
 
         smap = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
                              out_specs=P(), check_vma=False)
@@ -640,7 +635,8 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
         def step(params, xs):
             _check_stream_shape(xs)
             ys = smap(params, xs)
-            return ys[S - 1:]
+            with scope("emit"):
+                return ys[S - 1:]
         return jax.jit(step)
 
     def _check_stream_shape(xs):

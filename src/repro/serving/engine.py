@@ -36,6 +36,7 @@ from repro.core.compression import bfp8_decode, bfp8_encode
 from repro.models import decode_step, forward, init_cache, project_logits
 from repro.models.config import ArchConfig
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import host_span
 
 
 @dataclasses.dataclass
@@ -465,28 +466,41 @@ class GraphStreamServer:
         run lands one window observation and is re-scored — breaches fire
         the evaluator's ``on_breach`` hooks (e.g. a flight-recorder dump)
         and the verdict counts into ``smof_server_slo_evaluations_total``.
+
+        Each chunk is five host spans in turn (``obs.trace.host_span``):
+        ``smof.flush.stack`` (frames stacked and padded to ``B``),
+        ``.h2d`` (the copy to the device), ``.run`` (the step's dispatch),
+        ``.d2h`` (the wait for the step and the copy back) and ``.claim``
+        (outputs handed to their tickets).
         """
         out: dict[int, np.ndarray] = {}
         B = self.microbatches
         while self._pending:
             chunk, self._pending = self._pending[:B], self._pending[B:]
-            xs = np.stack([f for _, f in chunk])
-            pad = B - len(chunk)
-            if pad:
-                xs = np.concatenate(
-                    [xs, np.zeros((pad,) + xs.shape[1:], np.float32)])
-                self._c_padded.inc(pad)
+            with host_span("flush.stack"):
+                xs = np.stack([f for _, f in chunk])
+                pad = B - len(chunk)
+                if pad:
+                    xs = np.concatenate(
+                        [xs, np.zeros((pad,) + xs.shape[1:], np.float32)])
+                    self._c_padded.inc(pad)
             t_run = time.perf_counter()
-            ys = np.asarray(self.executor(jnp.asarray(xs)))
+            with host_span("flush.h2d"):
+                xs_dev = jnp.asarray(xs)
+            with host_span("flush.run"):
+                ys_dev = self.executor(xs_dev)
+            with host_span("flush.d2h"):
+                ys = np.asarray(ys_dev)
             run_s = time.perf_counter() - t_run
             self._c_streams.inc()
             now = time.perf_counter()
-            for (ticket, _), y in zip(chunk, ys):
-                out[ticket] = y
-                self._c_frames_out.inc()
-                t0 = self._submit_ts.pop(ticket, None)
-                if t0 is not None:
-                    self.latency.record(now - t0)
+            with host_span("flush.claim"):
+                for (ticket, _), y in zip(chunk, ys):
+                    out[ticket] = y
+                    self._c_frames_out.inc()
+                    t0 = self._submit_ts.pop(ticket, None)
+                    if t0 is not None:
+                        self.latency.record(now - t0)
             if self.slo is not None:
                 self.slo.observe(frames=len(chunk), seconds=run_s,
                                  spill_bytes=self._spill_bytes_per_stream,

@@ -136,3 +136,59 @@ def test_bfp8_dequant_compiles(shape_of, c):
 def test_streamed_matmul_padded_compiles(shape_of):
     _compile(lambda x, w: streamed_matmul_padded(x, w, static_fraction=0.5),
              shape_of((POSITIONS // 16, 512)), shape_of((512, 1024)))
+
+
+def _one_skip_plan(g):
+    """A one-stage plan of ``g`` that evicts its first long skip (an act's
+    edge to a concat) BFP8-compressed and nothing else."""
+    from repro.core.plan import ExecutionPlan, LayerPlan, StreamPlan
+    g.compute_buffer_depths()
+    skip = next((e.src, e.dst) for e in g.edges()
+                if g.vertex(e.src).kind == "act"
+                and g.vertex(e.dst).kind == "concat")
+    return ExecutionPlan(
+        model=g.name, device="tpu_v5e_kernel", n_stages=1,
+        layers={n: LayerPlan(name=n) for n in g.topo()},
+        streams=[StreamPlan(e.src, e.dst, evicted=(e.src, e.dst) == skip,
+                            codec="bfp8" if (e.src, e.dst) == skip else "none")
+                 for e in g.edges()],
+        topo_order=g.topo())
+
+
+def test_scopes_leave_the_pipelined_step_unchanged(shape_of, monkeypatch):
+    """The device scopes are metadata: the pipelined step of a small UNet
+    with one BFP8-evicted skip compiles to the same instructions with the
+    scope helper a no-op, once metadata and kernel names are stripped, and
+    every Pallas kernel of it carries an ``smof_`` name.  The hop is the
+    identity here (the step is lowered on a host without a chip, and the
+    lowering asks the host's devices for host memory)."""
+    import contextlib
+    import re
+
+    from _hlo import instructions_only
+    from repro.core import build_unet_exec
+    from repro.core.builders import exec_input_shape
+    from repro.runtime import executor
+    from repro.runtime.streamer import lower_plan_pipelined, pipeline
+
+    g = build_unet_exec(positions=POSITIONS // 16, levels=2)
+    plan = _one_skip_plan(g)
+
+    def step_text() -> str:
+        sx = lower_plan_pipelined(g, plan, microbatches=2,
+                                  kernel_mode="pallas", interpret=False)
+        params = {k: shape_of(v.shape) for k, v in sx.params.items()}
+        xs = shape_of((2,) + exec_input_shape(g))
+        return sx.fn.lower(params, xs).compile().as_text()
+
+    scoped = step_text()
+    for mod in (executor, pipeline):
+        monkeypatch.setattr(mod, "scope",
+                            lambda kind, name=None: contextlib.nullcontext())
+    bare = step_text()
+    assert "smof.emit" in scoped and "smof.codec.dec:" in scoped
+    assert "smof." not in bare
+    assert instructions_only(scoped) == instructions_only(bare)
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', scoped)
+    assert kernels and all(k.startswith("smof_") for k in kernels), kernels

@@ -30,6 +30,7 @@ import pytest
 import repro
 from repro.api import CompileSpec, Compiled
 from repro.core import DSEConfig, build_unet_exec
+from repro.core.graph import Graph
 from repro.core.plan import ExecutionPlan, LayerPlan, StreamPlan
 from repro.core.resources import Device
 from repro.obs import (LatencyHistogram, NULL_RECORDER, NullRecorder,
@@ -428,3 +429,115 @@ class TestLatencyHistogram:
         s = srv.latency.summary()
         assert s["count"] == len(tickets) == 3
         assert s["max_s"] > 0.0 and s["p95_s"] >= s["p50_s"] > 0.0
+
+
+# =============================================================================
+# smof.* host spans on the profiler's clock
+# =============================================================================
+
+def _profiled(fn):
+    """Run ``fn()`` under a CPU ``jax.profiler`` trace; returns the
+    ``smof.*`` events of the host plane as ``(name, start_ns, end_ns)``,
+    in start order."""
+    import glob
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.end_ns)
+                   for p in pd.planes if p.name == "/host:CPU"
+                   for line in p.lines for e in line.events
+                   if e.name.startswith("smof.")), key=lambda e: e[1])
+
+
+def _in_turn(events, names):
+    """The events of ``names``, each after the last one ended."""
+    got = [e for e in events if e[0] in names]
+    assert [e[0] for e in got] == list(names), got
+    for (_, _, end), (_, start, _) in zip(got, got[1:]):
+        assert start >= end
+    return got
+
+
+class TestProfilerSpans:
+    FLUSH = tuple(f"smof.flush.{p}"
+                  for p in ("stack", "h2d", "run", "d2h", "claim"))
+
+    def test_compile_and_run_spans(self):
+        box = {}
+
+        def work():
+            box["c"] = repro.compile(_spec(mode="pipelined", microbatches=2))
+            c = box["c"]
+            xs = jnp.zeros((2,) + c.input_shape(), jnp.float32)
+            jax.block_until_ready(c.run(xs))
+            jax.block_until_ready(c.run(xs))
+        events = _profiled(work)
+        _in_turn(events, ("smof.compile.search", "smof.compile.lower"))
+        runs = [e for e in events if e[0] == "smof.run"]
+        assert len(runs) == 2 and runs[1][1] >= runs[0][2]
+
+    def test_flush_spans_in_order_per_chunk(self):
+        c = repro.compile(_spec(mode="pipelined", microbatches=2))
+        srv = c.serve()
+        for _ in range(3):                  # two chunks, the second padded
+            srv.submit(np.zeros(c.input_shape(), np.float32))
+        events = _profiled(srv.flush)
+        flush = [e for e in events if e[0].startswith("smof.flush.")]
+        assert len(flush) == 2 * len(self.FLUSH)
+        _in_turn(flush[:5], self.FLUSH)
+        _in_turn(flush[5:], self.FLUSH)
+        assert flush[5][1] >= flush[4][2]
+
+    def test_recorder_spans_land_in_the_profile(self):
+        rec = TraceRecorder()
+
+        def spans():
+            with rec.span("frame"):
+                with rec.span("tick"):
+                    pass
+            with NULL_RECORDER.span("ignored"):
+                pass
+        events = _profiled(spans)
+        (frame,) = [e for e in events if e[0] == "smof.frame"]
+        (tick,) = [e for e in events if e[0] == "smof.tick"]
+        assert frame[1] <= tick[1] and tick[2] <= frame[2]
+        assert not [e for e in events if e[0] == "smof.ignored"]
+        assert [s["name"] for s in rec.spans()] == ["frame", "tick"]
+
+
+# =============================================================================
+# smof.* device scopes
+# =============================================================================
+
+class TestDeviceScopes:
+    def test_a_slash_in_a_vertex_name_reads_as_a_bar(self):
+        """A vertex name is metadata: a graph whose names hold ``/`` (as a
+        reloaded artifact may) compiles and runs bitwise as the same graph
+        with plain names, and each scope stays one ``op_name`` component."""
+        g = build_unet_exec()
+        d = g.to_json_dict()
+        new = {v["name"]: f"enc/{v['name']}" for v in d["vertices"]}
+        for v in d["vertices"]:
+            v["name"] = new[v["name"]]
+        for e in d["edges"]:
+            e["src"], e["dst"] = new[e["src"]], new[e["dst"]]
+        gs = Graph.from_json_dict(d)
+        B = 2
+        xs = jax.random.normal(jax.random.PRNGKey(0), (B, 64, 32),
+                               jnp.float32)
+        sx = lower_plan_pipelined(g, _two_stage_plan(g), microbatches=B,
+                                  kernel_mode="reference")
+        sxs = lower_plan_pipelined(gs, _two_stage_plan(gs), microbatches=B,
+                                   kernel_mode="reference")
+        params = {new[k]: w for k, w in sx.params.items()}
+        np.testing.assert_array_equal(np.asarray(sxs.fn(params, xs)),
+                                      np.asarray(sx(xs)))
+        text = sxs.fn.lower(params, xs).as_text(debug_info=True)
+        assert "/smof.conv:enc|conv_" in text
+        assert "smof.conv:enc/" not in text
