@@ -11,8 +11,9 @@ this module makes those decisions actually happen on an accelerator:
   are lossless, so their numerical effect is identity and only the traffic
   accounting changes.  On TPU the spill additionally hops through the
   host's pinned memory (``jax.device_put`` to ``jax.memory.Space.Host``
-  and back) so the bytes truly leave HBM; elsewhere the hop is a no-op
-  (the round-trip through the codec still executes).
+  and back) so the bytes truly leave HBM, each array packed lane-dense
+  first (:func:`_make_offchip_hop`); elsewhere the hop is a no-op (the
+  round-trip through the codec still executes).
 * **fragmented weights** (``LayerPlan.weight_static_fraction < 1``)
   dispatch to ``kernels/streamed_matmul.py``: the static row-panel of the
   weight matrix is pinned in VMEM and the dynamic remainder streams from
@@ -51,7 +52,10 @@ The lowering also emits a :class:`SpillReport`: per evicted/boundary edge,
 the raw and off-chip bit volumes.  For BFP8 the off-chip volume is computed
 from the actual mantissa/exponent buffer sizes, so when the channel count
 is a multiple of the block it is *bit-exact* against the DSE's
-compile-time ``c_bar = (8 + 8/block)/word_bits`` (Eq. 2/4).
+compile-time ``c_bar = (8 + 8/block)/word_bits`` (Eq. 2/4).  Each record
+also carries :class:`HopTraffic`: the arrays the TPU hop moves for the
+edge and the bytes they take on the host link in the chip's tiled layout,
+as the hop packs them and as they would cross unpacked.
 """
 from __future__ import annotations
 
@@ -83,6 +87,57 @@ BFP8_BLOCK = 32
 # Spill accounting
 # =============================================================================
 
+#: lanes of a TPU vector register: the minor dimension of every array in
+#: HBM is tiled to a multiple of this
+LANES = 128
+
+
+def _tiled_bytes(shape: tuple[int, ...], dtype) -> int:
+    """Bytes an array of rank >= 2 takes in a TPU's default tiled layout,
+    ``T(8,128)`` with ``32/bits`` rows packed per 32-bit sublane word (the
+    ``(4,1)`` of an int8 layout): its minor dimension padded to 128 lanes
+    and the one above it to ``8 * 32/bits`` rows.  The host link copies
+    whole tiles, so this is what one array moves each way."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = 8 * max(1, 4 // itemsize)
+    *lead, m, c = shape
+    return (math.prod(lead) * _round_up(m, rows) * _round_up(c, LANES)
+            * itemsize)
+
+
+def _lane_dense_rows(shape: tuple[int, ...]) -> int | None:
+    """Rows of the ``(n, 128)`` array the hop packs an array of ``shape``
+    into, or None where its minor dimension already fills whole lanes."""
+    if shape and shape[-1] % LANES == 0:
+        return None
+    return -(-math.prod(shape) // LANES)
+
+
+@dataclasses.dataclass(frozen=True)
+class HopTraffic:
+    """What the TPU's off-chip hop moves over the host link for one spilled
+    edge, per frame and in each direction (static: from the shapes the hop
+    sees).  Off a TPU the hop is the identity and nothing crosses."""
+    arrays: int = 0                # arrays the hop moves
+    repacked: int = 0              # of them, packed lane-dense first
+    link_bytes: int = 0            # tiled bytes, as the hop moves them
+    link_bytes_unpacked: int = 0   # tiled bytes, had each crossed unpacked
+
+    @classmethod
+    def of(cls, arrays) -> "HopTraffic":
+        """Traffic of the hop over ``arrays`` (anything with ``.shape`` and
+        ``.dtype``)."""
+        packed = [_lane_dense_rows(tuple(a.shape)) for a in arrays]
+        return cls(
+            arrays=len(arrays),
+            repacked=sum(n is not None for n in packed),
+            link_bytes=sum(
+                _tiled_bytes(tuple(a.shape) if n is None else (n, LANES),
+                            a.dtype) for a, n in zip(arrays, packed)),
+            link_bytes_unpacked=sum(_tiled_bytes(tuple(a.shape), a.dtype)
+                                    for a in arrays))
+
+
 @dataclasses.dataclass(frozen=True)
 class SpillRecord:
     """Off-chip traffic of one spilled stream (per frame)."""
@@ -93,6 +148,7 @@ class SpillRecord:
     raw_bits: int          # words * word_bits before the codec
     offchip_bits: int      # bits actually crossing the off-chip boundary
     exact: bool            # True when offchip_bits is compile-time exact
+    hop: HopTraffic = HopTraffic()   # the TPU host link's share of it
 
     @property
     def ratio(self) -> float:
@@ -117,6 +173,13 @@ class SpillReport:
             "streamed_weight_bits": self.streamed_weight_bits,
             "static_weight_bits": self.static_weight_bits,
             "total_offchip_bits": self.total_offchip_bits,
+            "hop_arrays": sum(s.hop.arrays for s in self.spills),
+            "hop_repacked": sum(s.hop.repacked for s in self.spills),
+            "host_link_bytes": sum(s.hop.link_bytes for s in self.spills),
+            "host_link_bytes_unpacked": sum(s.hop.link_bytes_unpacked
+                                            for s in self.spills),
+            "hop": {f"{s.src}->{s.dst}": dataclasses.asdict(s.hop)
+                    for s in self.spills},
         }
 
 
@@ -293,11 +356,21 @@ def analyze_plan(g: Graph, plan: ExecutionPlan | None, *,
             continue
         m, c = out_shape[u]
         raw_bits = m * c * e.word_bits
+        # what run_vertices hops: the BFP8 payload in pallas mode, else the
+        # (decoded) float32 stripe.  Both executors report this; in
+        # reference mode the pipelined one hops a BFP8 edge that crosses
+        # stages as its encoded payload instead.
+        hopped = [jax.ShapeDtypeStruct((m, c), jnp.float32)]
         if evicted and codec == "bfp8":
             off_bits, exact = _bfp8_offchip_bits(m, c), True
             fn = functools.partial(_bfp8_roundtrip, use_pallas=use_pallas,
                                    interpret=interpret)
             bfp8_edges.add((u, w))
+            if use_pallas:
+                c_pad = _round_up(c, BFP8_BLOCK)
+                hopped = [
+                    jax.ShapeDtypeStruct((m, c_pad), jnp.int8),
+                    jax.ShapeDtypeStruct((m, c_pad // BFP8_BLOCK), jnp.int8)]
         elif evicted and codec not in LOSSLESS_CODECS:
             raise ValueError(f"unsupported eviction codec {codec!r} "
                              f"on edge {(u, w)}")
@@ -312,7 +385,8 @@ def analyze_plan(g: Graph, plan: ExecutionPlan | None, *,
         spills.append(SpillRecord(
             src=u, dst=w, codec=codec,
             reason="evicted" if evicted else "stage_boundary",
-            raw_bits=raw_bits, offchip_bits=off_bits, exact=exact))
+            raw_bits=raw_bits, offchip_bits=off_bits, exact=exact,
+            hop=HopTraffic.of(hopped)))
         spill_fn[(u, w)] = fn
 
     streamed_bits = static_bits = 0
@@ -513,7 +587,9 @@ def run_vertices(g: Graph, an: PlanAnalysis, names: list[str], params: dict,
     ``smof.codec:<src>-<dst>`` for a reference-mode spill round-trip.
     The hop's copies to host memory and back carry no ``op_name`` once
     compiled (XLA makes them from memory spaces); a trace shows them by
-    memory space ``S(5)``.
+    memory space ``S(5)``.  The reshapes that pack a payload lane-dense
+    for the hop and unpack it carry the enclosing scope (``smof.tick`` in
+    the pipelined step).
     """
     internal = set(names)
     values: dict[str, jax.Array] = {}
@@ -648,7 +724,21 @@ def _make_offchip_hop() -> Callable[[jax.Array], jax.Array]:
     bytes truly leave HBM.  Other platforms have no separate device memory
     to leave, and the hop is the identity.  A TPU without a ``pinned_host``
     memory kind is an error, not a silent identity.  Called once at
-    lowering time, not per trace."""
+    lowering time, not per trace.
+
+    The copies move whole tiles of HBM's layout, 128 lanes wide, so an
+    array whose minor dimension is not a multiple of 128 (a BFP8 payload's
+    exponents, 64-channel mantissas) would carry its lane padding across
+    the link both ways.  The hop packs such an array lane-dense first:
+    flattened, zero-padded to whole rows of 128 and reshaped to
+    ``(n, 128)``, then sliced and reshaped back on return.  Bytes are
+    permuted, never changed.  :class:`HopTraffic` counts what crosses.
+
+    The packed array is held behind an optimization barrier: without it
+    XLA folds the unpacking reshape into a row pad that follows (every
+    payload that a dequant kernel reads is padded to its row blocks) and
+    drops the host copies along with the packing, so the payload never
+    leaves HBM."""
     device = jax.devices()[0]
     if device.platform != "tpu":
         return lambda x: x
@@ -658,9 +748,17 @@ def _make_offchip_hop() -> Callable[[jax.Array], jax.Array]:
             f"{device.device_kind} exposes no pinned_host memory (kinds: "
             f"{sorted(kinds)}): evicted spills cannot leave HBM")
 
-    def hop(x: jax.Array) -> jax.Array:
+    def move(x: jax.Array) -> jax.Array:
         y = jax.device_put(x, jax.memory.Space.Host)
         return jax.device_put(y, jax.memory.Space.Device)
+
+    def hop(x: jax.Array) -> jax.Array:
+        n = _lane_dense_rows(x.shape)
+        if n is None:
+            return move(x)
+        flat = jnp.pad(x.reshape(-1), (0, n * LANES - x.size))
+        y = jax.lax.optimization_barrier(move(flat.reshape(n, LANES)))
+        return y.reshape(-1)[:x.size].reshape(x.shape)
     return hop
 
 

@@ -192,3 +192,62 @@ def test_scopes_leave_the_pipelined_step_unchanged(shape_of, monkeypatch):
     kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                          r'"tpu_custom_call"', scoped)
     assert kernels and all(k.startswith("smof_") for k in kernels), kernels
+
+
+def test_report_counts_the_hops_host_link_bytes():
+    """The small UNet's one BFP8-evicted skip is a (11040, 32) stripe: the
+    hop moves its int8 mantissas (11040, 32) and exponents (11040, 1), both
+    packed lane-dense.  A tile of the int8 layout is 32 rows x 128 lanes."""
+    from repro.core import build_unet_exec
+    from repro.runtime.executor import lower_plan
+    from repro.runtime.streamer import lower_plan_pipelined
+
+    g = build_unet_exec(positions=POSITIONS // 16, levels=2)
+    plan = _one_skip_plan(g)
+    unpacked = 11040 * 128 + 11040 * 128       # each array's lanes padded
+    packed = (2784 * 128                       # 353,280 B = 2760 rows of 128
+              + 96 * 128)                      # 11,040 B = 87 rows of 128
+    for ex in (lower_plan(g, plan, kernel_mode="pallas"),
+               lower_plan_pipelined(g, plan, microbatches=2,
+                                    kernel_mode="pallas")):
+        s = ex.report.summary()
+        assert (s["hop_arrays"], s["hop_repacked"]) == (2, 2)
+        assert s["host_link_bytes"] == packed == 368_640
+        assert s["host_link_bytes_unpacked"] == unpacked == 2_826_240
+        [edge] = s["hop"].values()
+        assert edge == {"arrays": 2, "repacked": 2, "link_bytes": packed,
+                        "link_bytes_unpacked": unpacked}
+
+
+def test_pipelined_step_sends_lane_dense_arrays_to_host(topo, shape_of,
+                                                        monkeypatch):
+    """Compiled for the chip with the hop real, the small UNet's step moves
+    each array of the evicted payload to host memory and back (two copies
+    each), every one of them with a minor dimension of whole 128-lane rows.
+    The host copies are what XLA keeps: a hop it folds away never leaves
+    HBM."""
+    import re
+
+    from repro.core import build_unet_exec
+    from repro.core.builders import exec_input_shape
+    from repro.runtime.streamer import lower_plan_pipelined
+
+    g = build_unet_exec(positions=POSITIONS // 16, levels=2)
+    plan = _one_skip_plan(g)
+    # the hop asks jax.devices() for a TPU with host memory
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    sx = lower_plan_pipelined(g, plan, microbatches=2, kernel_mode="pallas",
+                              interpret=False)
+    monkeypatch.undo()
+    params = {k: shape_of(v.shape) for k, v in sx.params.items()}
+    xs = shape_of((2,) + exec_input_shape(g))
+    text = sx.fn.lower(params, xs).compile().as_text()
+    # a copy-start's tuple is (destination, source, context), each an
+    # array with its layout; host memory is the layout's S(5)
+    copies = [re.findall(r"(\w+\[[\d,]+\])\{([^}]*)\}", line)[:2]
+              for line in text.splitlines() if " copy-start(" in line]
+    to_host = [a for (a, dst), _ in copies if "S(5)" in dst]
+    to_device = [a for _, (a, src) in copies if "S(5)" in src]
+    assert len(to_host) == len(to_device) == sx.report.summary()["hop_arrays"]
+    assert all(int(a.rstrip("]").split(",")[-1]) % 128 == 0
+               for a in to_host + to_device), to_host

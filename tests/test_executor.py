@@ -256,6 +256,46 @@ class TestDevicePath:
         np.testing.assert_array_equal(np.asarray(jax.jit(hop)(x)),
                                       np.asarray(x))
 
+    # the unet368 payloads' shapes with 1/1840 of their rows: exponents of
+    # 64/128/256 channels, 64-channel mantissas, and a dense mantissa; one
+    # size that is not a multiple of 128; one 1-D array
+    HOP_SHAPES = [(96, 2), (96, 4), (96, 8), (96, 64), (37, 3), (96, 256),
+                  (1000,)]
+
+    @pytest.mark.parametrize("dtype", [jnp.int8, jnp.float32])
+    @pytest.mark.parametrize("shape", HOP_SHAPES)
+    def test_offchip_hop_on_tpu_repacks_bit_exactly(self, monkeypatch, shape,
+                                                     dtype):
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda *a, **k: [_FakeTpu(["device", "pinned_host"])])
+        hop = _make_offchip_hop()
+        key = jax.random.PRNGKey(sum(shape))
+        x = (jax.random.randint(key, shape, -128, 128, jnp.int8)
+             if dtype == jnp.int8 else jax.random.normal(key, shape, dtype))
+        y = jax.jit(hop)(x)
+        assert y.shape == x.shape and y.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(y).view(np.uint8),
+                                      np.asarray(x).view(np.uint8))
+
+    @pytest.mark.parametrize("shape", HOP_SHAPES)
+    def test_offchip_hop_sends_lane_dense_arrays_to_host(self, monkeypatch,
+                                                         shape):
+        """Lowered for a TPU, the one array the hop places in host memory
+        has a minor dimension of whole 128-lane rows."""
+        import re
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda *a, **k: [_FakeTpu(["device", "pinned_host"])])
+        hop = _make_offchip_hop()
+        x = jax.ShapeDtypeStruct(shape, jnp.int8)
+        text = jax.jit(hop).trace(x).lower(
+            lowering_platforms=("tpu",)).as_text()
+        sent = re.findall(r'_xla_buffer_placement = "pinned_host"\}\} : '
+                          r"\(tensor<([\dx]+)xi8>\)", text)
+        assert len(sent) == 1, text
+        assert int(sent[0].split("x")[-1]) % 128 == 0, sent
+
     def test_interpret_refused_for_pallas_on_tpu(self, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         with pytest.raises(ValueError, match="interpret"):
