@@ -1,14 +1,15 @@
 """Closed loop: back-to-back pipelined calls over a pool of distinct frames.
 
-Each call is ``Compiled.run`` of one ``(B, m, c)`` batch, cycling through a
-pool of ``pool_batches`` batches made on the device from the seed.  The
-loop keeps ``in_flight`` calls dispatched: once that many are out, it waits
-for the oldest before it sends the next, so the device never waits for the
-host's round trip.  When the window's time is up nothing more is sent; the
-loop waits for every call it sent and reads the clock after that wait.
-``fps`` is every frame of those calls over that whole time.  A seeded
-sample of the window's calls is kept, output and input, for the comparison
-with the reference.
+Each call is ``Compiled.run`` of one batch of B frames, ``(B,) +
+reference.input_shape(net)``, cycling through a pool of ``pool_batches``
+batches made on the device from the seed.  The loop keeps ``in_flight``
+calls dispatched: once that many are out, it waits for the oldest before
+it sends the next, so the device never waits for the host's round trip.
+When the window's time is up nothing more is sent; the loop waits for
+every call it sent and reads the clock after that wait.  ``fps`` is every
+frame of those calls over that whole time.  A seeded sample of the
+window's calls is kept, output and input, for the comparison with the
+reference.
 """
 from __future__ import annotations
 

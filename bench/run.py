@@ -192,7 +192,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic: dict, *,
     breakdown = None
     if trace:
         from bench import ops, system, trace_reduce
-        hlo = system.step_hlo(ctx.system)
+        hlo = system.step_hlo(ctx.system, net)
         instrs = ops.index(hlo) if hlo else None
         log(f"step HLO: {len(instrs or ())} instructions")
         try:

@@ -78,15 +78,18 @@ def plan_lines(c) -> list[str]:
             f"plan: {len(frag)} fragmented weights: {', '.join(frag)}"]
 
 
-def step_hlo(c) -> str | None:
+def step_hlo(c, net: list[dict]) -> str | None:
     """The compiled HLO text of the pipelined step the window drove, from
     the compile cache, or None where the step is not a jitted function (a
-    test's stand-in).  A trace names its device ops by its instructions."""
+    test's stand-in).  A trace names its device ops by its instructions.
+    The step takes ``(microbatches,) + input_shape(net)`` frames."""
     import jax
     import jax.numpy as jnp
+
+    from bench.reference import input_shape
     fn = c.executor.fn
     if not hasattr(fn, "lower"):
         return None
-    m, cin = c.input_shape()
-    xs = jax.ShapeDtypeStruct((c.executor.microbatches, m, cin), jnp.float32)
+    xs = jax.ShapeDtypeStruct((c.executor.microbatches,) + input_shape(net),
+                              jnp.float32)
     return fn.lower(c.executor.params, xs).compile().as_text()

@@ -51,7 +51,7 @@ def _compile(name: str, onchip_kbit: float | None, monkeypatch):
                             .replace(view, onchip_bits=onchip_kbit * 1e3))
     net = reference.model_layers(cfg)
     c = system.build(cfg, reference.make_weights(net, jax.random.PRNGKey(0)))
-    return c, system.step_hlo(c)
+    return c, system.step_hlo(c, net)
 
 
 def _scopes(text: str) -> set[str]:

@@ -1,26 +1,41 @@
 """Operations and bytes of a frame, from the layer shapes alone.
 
-Only the convs (1x1 channel mixing, ``y = x @ W``) count: a frame's pools,
-ReLUs, upsamples and concats are elementwise or data movement, under 0.1%
-of its operations.  Bytes are what a conv must move at least: its float32
-input and output once, and its weight once.
+Only the convs and deconvs count: a frame's pools, activations,
+upsamples, element-wise products and concats are element-wise or data
+movement, well under 1% of its operations.  A conv's MACs are
+``prod(out extent) * prod(k) * cin / groups * cout``, a deconv's
+``prod(in extent) * prod(k) * cin * cout``; a 1-D layer's (``m``
+positions, no ``shape``) ``m * cin * cout``.  Bytes are what a layer must
+move at least: its float32 input and output once, and its weights (a bias
+with them) once.
 """
 from __future__ import annotations
 
-WEIGHT_KINDS = ("conv", "deconv")
+import math
+
+from bench.reference import WEIGHT_KINDS, out_shape, weight_shapes
+
 F32_BYTES = 4
 
 
 def convs(net: list[dict]) -> list[dict]:
     """Per conv: name, MACs, FLOPs and the least bytes it moves."""
+    shapes = weight_shapes(net)
     out = []
     for L in net:
         if L["kind"] not in WEIGHT_KINDS:
             continue
-        m, cin, cout = L["m"], L["cin"], L["cout"]
-        macs = m * cin * cout
-        out.append({"name": L["name"], "macs": macs, "flops": 2 * macs,
-                    "bytes": F32_BYTES * (m * cin + m * cout + cin * cout)})
+        name = L["name"]
+        if "shape" in L:
+            n_in, n_out = math.prod(L["shape"]), math.prod(out_shape(L))
+        else:
+            n_in = n_out = L["m"]
+        w = math.prod(shapes[name])
+        macs = (n_out if L["kind"] == "conv" else n_in) * w
+        moved = (n_in * L["cin"] + n_out * L["cout"] + w
+                 + math.prod(shapes.get(f"{name}.bias", (0,))))
+        out.append({"name": name, "macs": macs, "flops": 2 * macs,
+                    "bytes": F32_BYTES * moved})
     return out
 
 
