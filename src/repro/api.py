@@ -260,10 +260,11 @@ def compile(spec: CompileSpec) -> "Compiled":
 class Compiled:
     """A deployable compiled design: executor + plan + provenance.
 
-    ``run(x)`` executes (staged/reference: one ``(m, c)`` frame ->
-    ``(L,)``; pipelined: a ``(B, m, c)`` stream -> ``(B, L)``, or a single
-    frame, broadcast through the pipeline, -> ``(L,)``).  ``serve()``
-    wraps the pipelined executor in a :class:`GraphStreamServer`;
+    ``run(x)`` executes (staged/reference: one frame -> ``(L,)``;
+    pipelined: a ``(B,) + frame`` stream -> ``(B, L)``, or a single frame,
+    broadcast through the pipeline, -> ``(L,)``); a frame is
+    :meth:`input_shape`.  ``serve()`` wraps the pipelined executor in a
+    :class:`GraphStreamServer`;
     ``report()`` unifies the Spill/Stream/Calibration reports; ``save`` /
     ``load`` round-trip a versioned plan artifact that reproduces
     bit-identically in a fresh process (weights are seeded).
@@ -307,14 +308,16 @@ class Compiled:
         import jax.numpy as jnp
         with host_span("run"):
             x = jnp.asarray(x)
-            if self.mode == "pipelined" and x.ndim == 2:
+            if (self.mode == "pipelined"
+                    and x.ndim == len(self.input_shape())):
                 # single-frame convenience: broadcast through the stream,
                 # every slot computes the same frame — return one output
                 B = self.executor.microbatches
                 return self.executor(jnp.broadcast_to(x, (B,) + x.shape))[0]
             return self.executor(x)
 
-    def input_shape(self) -> tuple[int, int]:
+    def input_shape(self) -> tuple[int, ...]:
+        """One frame's shape: ``(m, c)``, or ``(H, W, c)``."""
         return exec_input_shape(self.graph)
 
     # -- unified reporting ----------------------------------------------------
@@ -322,7 +325,10 @@ class Compiled:
         """One dict over all report families the toolflow produced:
         SpillReport (staged) / StreamReport (pipelined) summaries under
         ``traffic``, plan provenance, and — when the autotuner ran — its
-        summary incl. the CalibrationReport."""
+        summary incl. the CalibrationReport.  A spatial graph adds
+        ``line_buffers``: per k x k conv, the kernel's row blocks and the
+        halo it reads again, beside the DSE's Eq. 1 depth
+        (``runtime.executor.line_buffers``)."""
         out = {
             "model": self.model,
             "device": self.device,
@@ -340,6 +346,12 @@ class Compiled:
             out["autotune"] = self.autotune_result.summary()
         if self.model_check is not None:
             out["model_check"] = self.model_check.summary()
+        from .runtime.executor import line_buffers, resolve_kernel_mode
+        use_pallas = self.mode != "reference" and resolve_kernel_mode(
+            self.spec.resolved_kernel_mode(), self.spec.interpret)[0]
+        lb = line_buffers(self.graph, use_pallas=use_pallas)
+        if lb:
+            out["line_buffers"] = lb
         return out
 
     def metrics(self) -> dict:
@@ -389,15 +401,15 @@ class Compiled:
                                  path=self.spec.obs.flight_path)
         else:
             rec = TraceRecorder()
-        m, c = self.input_shape()
+        shape = self.input_shape()
         if x is None:
             rng = np.random.default_rng(self.spec.seed)
-            x = jnp.asarray(rng.normal(size=(m, c)).astype(np.float32))
+            x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
         else:
             x = jnp.asarray(x)
         mc = None
         if self.mode == "pipelined":
-            if x.ndim == 2:
+            if x.ndim == len(shape):
                 B = self.executor.microbatches
                 x = jnp.broadcast_to(x, (B,) + x.shape)
             y, mc = self.executor.run_traced(x, rec, metrics=self.registry)

@@ -2,7 +2,8 @@
 
 Structurally faithful reconstructions of UNet, UNet3D, YOLOv8n and X3D-M as
 SMOF layer graphs — most importantly with the *long skip connections* whose
-deep synchronisation buffers the eviction mechanism targets.  Channel
+deep synchronisation buffers the eviction mechanism targets; UNet's graph
+is executable as well (``build_unet``).  Channel
 configurations follow the original papers; Table III's MAC/param counts are
 matched by `benchmarks/table3_models.py` within a small tolerance (the paper
 itself notes "optimised UNet architectures tailored to the HW design
@@ -10,6 +11,7 @@ itself notes "optimised UNet architectures tailored to the HW design
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable
 
@@ -69,40 +71,69 @@ class _B:
 # UNet (Ronneberger et al.) — input (3, 368, 480); 4 skip connections
 # -----------------------------------------------------------------------------
 
+def _spatial_exec(g: Graph, name: str, cin: int, cout: int,
+                  hw: tuple[int, ...], hw_out: tuple[int, ...] | None = None,
+                  k: int = 1, stride: int = 1) -> None:
+    """Record a spatial vertex's executable spec: channels, the extent of
+    its input (``hw``) and output (``hw_out``) images, kernel and stride.
+    Its activations flow as the images' row-major ``(m, c)`` stripes."""
+    hw_out = tuple(hw_out or hw)
+    g.vertex(name).meta["exec"] = {
+        "cin": cin, "cout": cout, "m": math.prod(hw),
+        "m_out": math.prod(hw_out), "hw": tuple(hw), "hw_out": hw_out,
+        "k": k, "stride": stride}
+
+
 def build_unet(input_hw: tuple[int, int] = (368, 480), cin: int = 3,
                base: int = 64, levels: int = 5, n_classes: int = 32) -> Graph:
+    """UNet as published, costed and executable in one graph: per encoder
+    level two 3x3 convs + ReLU and a 2x2 max pool, per decoder level a 2x2
+    stride-2 up-conv, the skip-first concat and two 3x3 convs + ReLU, a
+    1x1 conv to the classes.  Every vertex carries its spatial
+    ``meta["exec"]`` (:func:`_spatial_exec`)."""
     g = Graph("unet")
     b = _B(g)
+    x = functools.partial(_spatial_exec, g)
     inp, sp = b.simple(None, "input", cin, input_hw)
+    x(inp, cin, cin, sp)
     skips: list[tuple[str, int, tuple[int, int]]] = []
     prev, c = inp, cin
     # encoder
     for lv in range(levels):
         cout = base * (2 ** lv)
-        prev, sp = b.conv(prev, c, cout, sp)
-        prev, sp = b.simple(prev, "act", cout, sp)
-        prev, sp = b.conv(prev, cout, cout, sp)
-        prev, sp = b.simple(prev, "act", cout, sp)
+        for c_in in (c, cout):
+            prev, _ = b.conv(prev, c_in, cout, sp)
+            x(prev, c_in, cout, sp, k=3)
+            prev, _ = b.simple(prev, "act", cout, sp)
+            x(prev, cout, cout, sp)
         c = cout
         if lv < levels - 1:
             skips.append((prev, c, sp))
-            prev, sp = b.simple(prev, "pool", c, sp,
-                                out_spatial=tuple(s // 2 for s in sp))
+            half = tuple(s // 2 for s in sp)
+            prev, _ = b.simple(prev, "pool", c, sp, out_spatial=half)
+            x(prev, c, c, sp, half, k=2, stride=2)
+            sp = half
     # decoder with long skips
     for lv in reversed(range(levels - 1)):
         cout = base * (2 ** lv)
-        prev, sp = b.conv(prev, c, cout, sp, k=2, kind="deconv")
-        sp = tuple(s * 2 for s in sp)
-        g.vertex(prev).out_words = cout * math.prod(sp)
+        prev, _ = b.conv(prev, c, cout, sp, k=2, kind="deconv")
+        double = tuple(s * 2 for s in sp)
+        g.vertex(prev).out_words = cout * math.prod(double)
+        x(prev, c, cout, sp, double, k=2, stride=2)
+        sp = double
         skip, sc, ssp = skips.pop()
-        prev, sp = b.simple([skip, prev], "concat", cout + sc, sp)
-        prev, sp = b.conv(prev, cout + sc, cout, sp)
-        prev, sp = b.simple(prev, "act", cout, sp)
-        prev, sp = b.conv(prev, cout, cout, sp)
-        prev, sp = b.simple(prev, "act", cout, sp)
+        prev, _ = b.simple([skip, prev], "concat", cout + sc, sp)
+        x(prev, cout + sc, cout + sc, sp)
+        for c_in in (cout + sc, cout):
+            prev, _ = b.conv(prev, c_in, cout, sp)
+            x(prev, c_in, cout, sp, k=3)
+            prev, _ = b.simple(prev, "act", cout, sp)
+            x(prev, cout, cout, sp)
         c = cout
-    prev, sp = b.conv(prev, c, n_classes, sp, k=1)
-    b.simple(prev, "output", n_classes, sp)
+    prev, _ = b.conv(prev, c, n_classes, sp, k=1)
+    x(prev, c, n_classes, sp)
+    out, _ = b.simple(prev, "output", n_classes, sp)
+    x(out, n_classes, n_classes, sp)
     return g
 
 
@@ -500,15 +531,18 @@ def get_model(name: str, registry: dict | None = None) -> Callable[..., Graph]:
     raise KeyError(f"unknown model {name!r}; known models: {', '.join(known)}")
 
 
-def exec_input_shape(g: Graph) -> tuple[int, int]:
-    """The (positions, channels) input stripe shape of an executable graph."""
+def exec_input_shape(g: Graph) -> tuple[int, ...]:
+    """One frame's shape for an executable graph: the ``(positions,
+    channels)`` input stripe, or ``(H, W, channels)`` for a spatial one."""
     for v in g.vertices():
         if v.kind == "input":
             spec = v.meta.get("exec")
             if spec is None:
                 raise ValueError(
                     f"graph {g.name!r} has no executable input spec — use a "
-                    f"build_*_exec builder (see EXEC_MODELS)")
+                    f"build_*_exec builder (see EXEC_MODELS) or build_unet")
+            if "hw" in spec:
+                return tuple(spec["hw"]) + (spec["cin"],)
             return (spec["m"], spec["cin"])
     raise ValueError(f"graph {g.name!r} has no input vertex")
 

@@ -9,8 +9,11 @@ Layout (docs/KERNELS.md has the full picture):
   that consumes a sliding window of rows per cycle.  The channel-mixing
   ops (``conv``/``matmul``/``deconv``) additionally tile the *output*
   channel axis by ``bc`` with the **full K axis per grid step** — a
-  single ``jnp.dot`` per tile, no K-split accumulation, which is what
-  makes tiled results bit-exact against the untiled reference dot.
+  single ``jnp.dot`` per tile, no K-split accumulation, so a tile never
+  changes which products an output sums.  The order of that sum is the
+  backend dot's: XLA's CPU dot picks it by the operands' shapes, so off a
+  TPU two tilings may differ by reassociation, a few ulps
+  (``tests/test_properties.py`` holds them to that bound).
 * **fused ingress**: when the op's input edge arrives BFP8-evicted, the
   kernel takes the spill payload (int8 mantissas + per-block int8 shared
   exponents) and dequantises per block *inside* the ``pallas_call``
@@ -120,9 +123,11 @@ def conv2d(x, w, *, payload=None, encode=False, block: int = 32,
     additionally emits the output's BFP8 spill payload from the same
     ``pallas_call`` and returns ``(y, (man, exp))``.
 
-    Bit-exact contract: ``y`` equals ``jnp.dot(x, w)`` (with ``x`` the
-    dequantised input where applicable) and the egress payload equals
-    ``bfp8_quant`` of the block-padded ``y`` — for every ``bm``/``bc``.
+    Contract: ``y`` is ``jnp.dot(x, w)`` (with ``x`` the dequantised
+    input where applicable), each output the same full-K sum for every
+    ``bm``/``bc`` (its order is the backend dot's: module doc), and the
+    egress payload is bitwise ``bfp8_quant`` of the block-padded ``y``
+    the kernel emits.
     """
     cin, n = w.shape
     if payload is not None:
@@ -505,5 +510,150 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
     return y[:m], (man_o[:m], exp_o[:m])
 
 
-__all__ = ["conv2d", "dwconv", "pool", "act_relu", "DEFAULT_BM",
-           "DEFAULT_BC"]
+# =============================================================================
+# conv_kxk — k x k 'same' conv over a row-major (H*W, C) stripe: line buffer
+# =============================================================================
+
+#: bytes of one row block's input in VMEM (lanes padded to 128), the cap
+#: on how many image rows a block takes
+KXK_BLOCK_BYTES = 2 * 2 ** 20
+KXK_VMEM_LIMIT = 64 * 2 ** 20       # scoped VMEM the kernel may claim
+KXK_BC = 256                        # out-channel block of a wide conv
+
+
+@functools.lru_cache(maxsize=None)
+def kxk_tiles(h: int, w: int, k: int, cin: int, cout: int
+              ) -> tuple[int, int, int]:
+    """``(rows, wp, bc)`` of :func:`conv_kxk` on an ``h x w`` image: image
+    rows per row block, the row width the kernel walks (``w`` padded to
+    whole sublane tiles) and the out-channel block.
+
+    ``rows`` is a multiple of ``2 * (k // 2)`` (the halo block's size must
+    divide the row offset it starts at), its input block holds at most
+    ``KXK_BLOCK_BYTES`` unless one step of rows exceeds it, and among those
+    it reads the fewest image rows per frame, halos included."""
+    p = k // 2
+    step = max(2 * p, 1)
+    wp = _round_up(w, BM_ALIGN)
+    row_bytes = wp * _round_up(cin, BC_ALIGN) * 4
+    cap = max(step, KXK_BLOCK_BYTES // row_bytes // step * step)
+    rows = min(range(step, min(cap, _round_up(h, step)) + 1, step),
+               key=lambda r: (-(-h // r) * (r + 2 * p), -r))
+    bc = cout if cout <= KXK_BC else KXK_BC
+    return rows, wp, bc
+
+
+def kxk_halo_bytes(h: int, w: int, k: int, cin: int, cout: int) -> int:
+    """HBM bytes of halo rows :func:`conv_kxk` reads again per frame: each
+    row block's ``k // 2`` image rows above and below, float32."""
+    rows, wp, _ = kxk_tiles(h, w, k, cin, cout)
+    return -(-h // rows) * 2 * (k // 2) * wp * cin * 4
+
+
+def _kxk_kernel(*refs, k, rows, wp, w):
+    """One (row block, out-channel block) step.  At the first channel
+    block the row block and its halo are laid out in the scratch ``k``
+    times side by side along the lanes, once per column tap ``dx``:
+    shifted along the image row by ``dx - k//2`` (a sublane roll within
+    each row) with the columns that fall off the row's edge zeroed.
+    Every step then sums ``k`` dots, one per row tap ``dy``: the scratch
+    from image row ``dy`` on (an aligned slice of whole rows) against the
+    ``(k * cin, bc)`` weights of that row of taps, so each dot contracts
+    all ``k`` column taps at once."""
+    p = k // 2
+    if p:
+        xm_ref, xh_ref, w_ref, o_ref, xs_ref = refs
+    else:
+        xm_ref, w_ref, o_ref, xs_ref = refs
+    rr = rows + 2 * p
+    cin = xm_ref.shape[-1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _lay_out():
+        x = xm_ref[...]
+        if p:
+            x = jnp.concatenate([x, xh_ref[...]], axis=0)
+        x3 = x.reshape(rr, wp, cin)
+        col = jax.lax.broadcasted_iota(jnp.int32, x3.shape, 1)
+        for dx in range(k):
+            s = dx - p
+            xs = x3
+            if s:
+                xs = pltpu.roll(x3, shift=(-s) % wp, axis=1)
+                xs = jnp.where((col + s >= 0) & (col + s < w), xs, 0.0)
+            xs_ref[:, dx * cin:(dx + 1) * cin] = xs.reshape(rr * wp, cin)
+
+    acc = None
+    for dy in range(k):
+        part = jnp.dot(xs_ref[dy * wp:(dy + rows) * wp, :], w_ref[dy],
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
+        acc = part if acc is None else acc + part
+    o_ref[...] = acc
+
+
+def conv_kxk(x, w, *, hw: tuple[int, int], interpret: bool = False):
+    """``k x k`` 'same' conv (stride 1, zero padding ``k // 2``) of an image
+    held as its row-major ``(H*W, cin)`` stripe; ``w`` is HWIO
+    ``(k, k, cin, cout)``.  Returns the ``(H*W, cout)`` stripe, float32.
+
+    The paper's line buffer (Eq. 1 depth ``k * W * cin``) on a row-block
+    grid: a step owns ``rows`` whole image rows (:func:`kxk_tiles`) and an
+    out-channel block.  Its input arrives as two blocks of the same
+    row-padded stripe, the ``rows`` image rows and the ``2 * (k // 2)``
+    rows below them, so each block's halo (``k // 2`` rows above and
+    below) is read again from HBM and never built: no im2col.  Column
+    taps shift within a row in VMEM, mask the row's edges and sit side by
+    side, so a row of taps is one dot of depth ``k * cin``.
+
+    Tile contract: ``rows`` is a multiple of ``2 * (k // 2)``; the row
+    width walked is ``W`` padded to a multiple of 8 (a row of zeros past
+    the edge, sliced off after); ``bc`` is ``cout`` up to ``KXK_BC``,
+    else ``KXK_BC``, and ``cout`` is padded to it.  Each output value is
+    the same ``k`` dots summed in the same order for any tile, so the
+    tiling never changes which products it sums.  Its dots run at
+    ``HIGHEST`` precision, float32 products on a TPU as off it: through
+    the published UNet's 18 such convs a bfloat16 pass would make any
+    order of summation other than XLA's conv's grow into a frame's
+    bfloat16 rounding noise (PERF.md, section 6)."""
+    h, wd = hw
+    k, k2, cin, cout = w.shape
+    assert k == k2 and k % 2 == 1, w.shape
+    assert x.shape == (h * wd, cin), (x.shape, hw, w.shape)
+    p = k // 2
+    rows, wp, bc = kxk_tiles(h, wd, k, cin, cout)
+    hp = _round_up(h, rows)
+    npad = _round_up(cout, bc)
+    x3 = x.reshape(h, wd, cin)
+    xp = jnp.pad(x3, ((p, hp - h + p), (0, wp - wd), (0, 0)))
+    xp = xp.reshape((hp + 2 * p) * wp, cin)
+    wpad = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, npad - cout)))
+    wpad = wpad.reshape(k, k * cin, npad)       # a row of taps: dx, then cin
+    in_specs = [pl.BlockSpec((rows * wp, cin), lambda i, j: (i, 0))]
+    args = [xp]
+    if p:
+        halo = rows // (2 * p)
+        in_specs.append(pl.BlockSpec((2 * p * wp, cin),
+                                     lambda i, j: ((i + 1) * halo, 0)))
+        args.append(xp)
+    in_specs.append(pl.BlockSpec((k, k * cin, bc), lambda i, j: (0, 0, j)))
+    y = pl.pallas_call(
+        functools.partial(_kxk_kernel, k=k, rows=rows, wp=wp, w=wd),
+        grid=(hp // rows, npad // bc), in_specs=in_specs,
+        out_specs=pl.BlockSpec((rows * wp, bc), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((hp * wp, npad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(((rows + 2 * p) * wp, k * cin),
+                                   jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=KXK_VMEM_LIMIT),
+        interpret=interpret, name="smof_conv_kxk",
+    )(*args, wpad)
+    y = y[:h * wp, :cout]
+    if wp != wd:
+        y = y.reshape(h, wp, cout)[:, :wd].reshape(h * wd, cout)
+    return y
+
+
+__all__ = ["conv2d", "dwconv", "pool", "act_relu", "conv_kxk", "kxk_tiles",
+           "DEFAULT_BM", "DEFAULT_BC"]

@@ -61,8 +61,8 @@ from repro.runtime.streamer import (StreamingExecutor, eq5_sequential_time,
 MOVES = ("split", "merge", "evict", "unevict", "frag", "tile")
 
 # candidate Pallas tile sizes for the "tile" move (0 = kernel default).
-# Results are tile-independent (bit-exact — tests/test_properties.py), so
-# these are pure performance knobs; only proposed when the resolved kernel
+# A tile never changes which products an output sums, only, off a TPU,
+# their order (tests/test_properties.py), so these are performance knobs; only proposed when the resolved kernel
 # mode actually dispatches to the streaming_conv Pallas bodies.  Every
 # choice is a tile Mosaic accepts: rows in multiples of 8, channels in
 # multiples of 128 (streaming_conv._tile).

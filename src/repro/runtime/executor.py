@@ -25,7 +25,7 @@ this module makes those decisions actually happen on an accelerator:
 
 Executable graphs come from ``core.builders.build_*_exec``: every vertex
 carries ``meta["exec"] = {cin, cout, m[, m_out]}`` and activations flow as
-``(positions, channels)`` f32 stripes.  Supported ops:
+``(positions, channels)`` f32 stripes.  Supported ops of these 1-D graphs:
 
   ========== =====================================================
   kind       semantics
@@ -47,6 +47,32 @@ carries ``meta["exec"] = {cin, cout, m[, m_out]}`` and activations flow as
   concat     channel concatenation, predecessor order
   output     ravel-and-concatenate all inputs into one vector
   ========== =====================================================
+
+A spatial graph (``core.builders.build_unet``) adds ``hw``/``hw_out`` (the
+input and output image extents), ``k`` and ``stride`` to each spec; its
+frame enters as ``(H, W, C)`` and every activation after the input flows
+as the image's row-major ``(H*W, C)`` stripe, so crossings, codecs and the
+hop see the same ``(m, c)`` stripes as above.  Its weights are HWIO
+``(k, k, cin, cout)``:
+
+  ========== =====================================================
+  input      flatten the ``(H, W, C)`` frame to its stripe
+  conv       k x k 'same' conv, stride 1 (``streaming_conv.conv_kxk``,
+             the line-buffer kernel; a conv whose whole contraction
+             ``k*k*cin`` fits one 128-deep MXU pass, the RGB stem, runs
+             as XLA's conv); k = 1 is a matmul
+  deconv     k = stride transposed conv: one matmul to ``k*k*cout``
+             channels, then depth-to-space
+  pool       k = stride max pool (XLA)
+  ========== =====================================================
+
+act, concat and output are the 1-D kinds' bodies.  Every product of a
+spatial conv, deconv and matmul is a float32 one (``HIGHEST``): through
+the published UNet's 23 convs, a bfloat16 pass makes any difference in
+the order of a sum grow into bfloat16 rounding noise (PERF.md, section 6),
+so the program could not be checked against a reference.  The matmuls
+(k = 1, deconvs) and the stem are XLA's; a fragmented spatial weight is
+refused (ROADMAP B1), and only act fuses the BFP8 codec.
 
 The lowering also emits a :class:`SpillReport`: per evicted/boundary edge,
 the raw and off-chip bit volumes.  For BFP8 the off-chip volume is computed
@@ -78,6 +104,9 @@ from ..kernels.streamed_matmul import (_round_up, splits_weight,
 from ..obs.trace import scope
 
 WEIGHT_KINDS = ("conv", "deconv", "matmul")
+#: a k x k conv whose whole contraction is at most this deep runs as XLA's
+#: conv: the Pallas kernel would feed the MXU ``cin`` lanes a tap
+XLA_CONV_MAX_K = 128
 TEMPORAL_KINDS = ("dwconv",)
 LOSSLESS_CODECS = ("none", "rle", "huffman")
 BFP8_BLOCK = 32
@@ -200,13 +229,26 @@ def _exec_spec(g: Graph, name: str) -> dict:
     if spec is None:
         raise ValueError(
             f"vertex {name!r} has no meta['exec'] — executable lowering "
-            f"needs graphs built by core.builders.build_*_exec")
+            f"needs graphs built by core.builders.build_*_exec or "
+            f"build_unet")
     return spec
+
+
+def weight_shape(kind: str, spec: dict) -> tuple[int, ...]:
+    """A weighty vertex's weight: ``(taps, c)`` for a dwconv, HWIO
+    ``(k, k, cin, cout)`` for a spatial conv or deconv, else
+    ``(cin, cout)``."""
+    if kind in TEMPORAL_KINDS:
+        return (spec.get("taps", 3), spec["cout"])
+    if "hw" in spec:
+        return (spec["k"], spec["k"], spec["cin"], spec["cout"])
+    return (spec["cin"], spec["cout"])
 
 
 def init_params(g: Graph, seed: int = 0,
                 dtype=jnp.float32) -> dict[str, jax.Array]:
-    """Deterministic per-vertex weights for every weighty executable op."""
+    """Deterministic per-vertex weights for every weighty executable op,
+    N(0, 1/fan-in) (a dwconv's fan-in is its taps)."""
     params: dict[str, jax.Array] = {}
     for v in g.vertices():
         if v.kind not in WEIGHT_KINDS and v.kind not in TEMPORAL_KINDS:
@@ -214,14 +256,13 @@ def init_params(g: Graph, seed: int = 0,
         spec = _exec_spec(g, v.name)
         key = jax.random.fold_in(jax.random.PRNGKey(seed),
                                  zlib.crc32(v.name.encode()))
+        shape = weight_shape(v.kind, spec)
         if v.kind in TEMPORAL_KINDS:
-            taps = spec.get("taps", 3)
             params[v.name] = jax.random.normal(
-                key, (taps, spec["cout"]), dtype) / math.sqrt(taps)
+                key, shape, dtype) / math.sqrt(shape[0])
         else:
-            scale = 1.0 / math.sqrt(spec["cin"])
-            params[v.name] = scale * jax.random.normal(
-                key, (spec["cin"], spec["cout"]), dtype)
+            scale = 1.0 / math.sqrt(math.prod(shape[:-1]))
+            params[v.name] = scale * jax.random.normal(key, shape, dtype)
     return params
 
 
@@ -247,6 +288,52 @@ def _dwconv(x: jax.Array, w: jax.Array) -> jax.Array:
     xp = jnp.pad(x, ((pad, taps - 1 - pad), (0, 0)))
     m = x.shape[0]
     return sum(w[k][None, :] * xp[k:k + m] for k in range(taps))
+
+
+def xla_conv(spec: dict) -> bool:
+    """Whether a spatial k x k conv runs as XLA's conv, not the line-buffer
+    kernel: its whole contraction fits one MXU pass (the RGB stem)."""
+    return spec["k"] ** 2 * spec["cin"] <= XLA_CONV_MAX_K
+
+
+def _conv_same(x: jax.Array, w: jax.Array, hw: tuple[int, int]) -> jax.Array:
+    """k x k 'same' conv of a ``(H*W, cin)`` stripe by XLA, HWIO ``w``,
+    float32 products."""
+    k = w.shape[0]
+    y = jax.lax.conv_general_dilated(
+        x.reshape((1,) + tuple(hw) + (x.shape[1],)), w, (1, 1),
+        [(k // 2, k // 2)] * 2, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return y.reshape(-1, w.shape[-1]).astype(x.dtype)
+
+
+def _tap_matrix(w: jax.Array) -> jax.Array:
+    """HWIO ``(k, k, cin, cout)`` as the ``(cin, k*k*cout)`` matrix whose
+    columns run over taps, then output channels."""
+    return w.transpose(2, 0, 1, 3).reshape(w.shape[2], -1)
+
+
+def _depth_to_space(y: jax.Array, spec: dict) -> jax.Array:
+    """A deconv's ``(h*w, k*k*cout)`` matmul as its ``(h*k * w*k, cout)``
+    output stripe: tap ``(a, b)`` of input ``(i, j)`` is output
+    ``(i*k + a, j*k + b)``."""
+    (h, w), k, c = spec["hw"], spec["k"], spec["cout"]
+    return (y.reshape(h, w, k, k, c).transpose(0, 2, 1, 3, 4)
+            .reshape(h * k * w * k, c))
+
+
+def _max_pool(x: jax.Array, spec: dict) -> jax.Array:
+    """k = stride max pool of a ``(H*W, c)`` stripe (no padding: trailing
+    rows and columns that fill no window drop out)."""
+    if spec["k"] != spec["stride"]:
+        raise NotImplementedError(
+            f"max pool k={spec['k']} stride={spec['stride']}: only k = "
+            f"stride pools execute (ROADMAP B2)")
+    (h, w), (ho, wo), k = spec["hw"], spec["hw_out"], spec["k"]
+    c = x.shape[1]
+    x = x.reshape(h, w, c)[:ho * k, :wo * k]
+    return x.reshape(ho, k, wo, k, c).max(axis=(1, 3)).reshape(ho * wo, c)
 
 
 def bfp8_spill_encode(x: jax.Array, *, use_pallas: bool,
@@ -309,7 +396,7 @@ class PlanAnalysis:
     use_pallas: bool
     interpret: bool
     in_vertex: str
-    in_shape: tuple[int, int]
+    in_shape: tuple[int, ...]         # one frame: (m, c), or (H, W, c)
     #: evicted edges carrying a BFP8 spill — the payload-routed set the
     #: pallas-mode executors encode once per producer / decode per consumer
     bfp8_edges: set = dataclasses.field(default_factory=set)
@@ -398,21 +485,21 @@ def analyze_plan(g: Graph, plan: ExecutionPlan | None, *,
         lp = layers.get(name)
         f = lp.weight_static_fraction if lp is not None else 1.0
         frac[name] = f
-        spec = _exec_spec(g, name)
-        if v.kind in TEMPORAL_KINDS:
-            wbits = spec.get("taps", 3) * spec["cout"] * v.weight_bits
-        else:
-            wbits = spec["cin"] * spec["cout"] * v.weight_bits
+        wbits = (math.prod(weight_shape(v.kind, _exec_spec(g, name)))
+                 * v.weight_bits)
         static_bits += int(round(f * wbits))
         streamed_bits += int(round((1.0 - f) * wbits))
 
     in_vertex = next(n for n in topo if g.vertex(n).kind == "input")
+    in_spec = _exec_spec(g, in_vertex)
+    in_shape = (tuple(in_spec["hw"]) + (in_spec["cin"],) if "hw" in in_spec
+                else out_shape[in_vertex])
     return PlanAnalysis(
         topo=topo, out_shape=out_shape, spills=spills, spill_fn=spill_fn,
         frac=frac, stage_of=stage_of, streamed_weight_bits=streamed_bits,
         static_weight_bits=static_bits, use_pallas=use_pallas,
         interpret=interpret, in_vertex=in_vertex,
-        in_shape=out_shape[in_vertex], bfp8_edges=bfp8_edges,
+        in_shape=in_shape, bfp8_edges=bfp8_edges,
         tile_bm=(plan.tile_bm if plan is not None else 0),
         tile_bc=(plan.tile_bc if plan is not None else 0))
 
@@ -426,11 +513,15 @@ def apply_vertex(v, ins: list[jax.Array], params: dict, x: jax.Array | None,
     dwconv, pool and act bodies dispatch to the ``kernels/streaming_conv``
     Pallas kernels (bit-exact vs the reference bodies, every tile size);
     fragmented weight layers keep the ``streamed_matmul`` fragmentation
-    kernel, whose codec stays unfused.  Data-movement and variadic kinds
+    kernel, whose codec stays unfused.  A spatial graph's input, conv,
+    deconv and pool run :func:`_apply_spatial` (module doc).  Data-movement and variadic kinds
     (upsample/add/mul/concat/output) run their reference bodies in every
     mode — the registry in ``kernels/ops.py`` records which is which.
     """
     an = analysis
+    spec = v.meta.get("exec", {})
+    if "hw" in spec and v.kind in ("input", "conv", "deconv", "pool"):
+        return _apply_spatial(v, spec, ins, params, x, an)
     if v.kind == "input":
         assert x is not None, "input vertex fed without a graph input"
         return x
@@ -478,6 +569,28 @@ def apply_vertex(v, ins: list[jax.Array], params: dict, x: jax.Array | None,
     raise ValueError(f"op kind {v.kind!r} has no executable lowering")
 
 
+def _apply_spatial(v, spec: dict, ins, params, x, an: PlanAnalysis):
+    """The spatial bodies of :func:`apply_vertex` (module doc)."""
+    if v.kind == "input":
+        assert x is not None, "input vertex fed without a graph input"
+        return x.reshape(spec["m"], spec["cin"])
+    if v.kind == "pool":
+        return _max_pool(ins[0], spec)
+    w = params[v.name]
+    if an.frac.get(v.name, 1.0) < 1.0:
+        raise NotImplementedError(
+            f"{v.name}: the plan fragments a spatial {v.kind}'s weight; "
+            f"only 1-D graphs stream weights (ROADMAP B1)")
+    if v.kind == "conv" and spec["k"] > 1:
+        if an.use_pallas and not xla_conv(spec):
+            return SC.conv_kxk(ins[0], w, hw=tuple(spec["hw"]),
+                               interpret=an.interpret)
+        return _conv_same(ins[0], w, tuple(spec["hw"]))
+    y = jnp.dot(ins[0], _tap_matrix(w), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+    return _depth_to_space(y, spec) if v.kind == "deconv" else y
+
+
 # =============================================================================
 # Kernel-level vertex lowering: Pallas bodies + fused BFP8 boundary codec
 # =============================================================================
@@ -492,11 +605,16 @@ def vertex_body(g: Graph, name: str, an: PlanAnalysis) -> str:
     """Which body :func:`apply_vertex` runs for one vertex: ``"pallas"`` or
     ``"reference"``.  Pallas mode still runs reference bodies for the
     data-movement kinds and for a fragmented weight too small to split
-    (``streamed_matmul_padded``'s plain-dot fallback) — this names them,
-    so a silent fallback shows up in a script's output."""
+    (``streamed_matmul_padded``'s plain-dot fallback), and for a spatial
+    graph's max pools, matmuls and XLA-run stem — this names them, so a
+    silent fallback shows up in a script's output."""
     v = g.vertex(name)
+    spec = _exec_spec(g, name)
     if not an.use_pallas or v.kind not in FUSABLE_KINDS:
         return "reference"
+    if "hw" in spec and v.kind != "act":
+        return ("pallas" if v.kind == "conv" and spec["k"] > 1
+                and not xla_conv(spec) else "reference")
     if v.kind in WEIGHT_KINDS and an.frac.get(name, 1.0) < 1.0:
         return ("pallas" if splits_weight(_exec_spec(g, name)["cin"])
                 else "reference")
@@ -518,13 +636,16 @@ def _lower_vertex(g: Graph, name: str, an: PlanAnalysis) -> VertexLowering:
     edge (ingress dequant inside the ``pallas_call``) and/or emits its
     output's spill payload from the same call (egress quant).  Multi-input
     consumers and fragmented weight layers fall back to the standalone
-    ``bfp8_spill_decode``/``bfp8_spill_encode`` dispatches."""
+    ``bfp8_spill_decode``/``bfp8_spill_encode`` dispatches, and so does
+    every spatial kind but act."""
     v = g.vertex(name)
+    spec = _exec_spec(g, name)
     needs_payload = an.use_pallas and any(
         (name, s) in an.bfp8_edges for s in g.successors(name))
     fusable = (an.use_pallas and v.kind in FUSABLE_KINDS
                and not (v.kind in WEIGHT_KINDS
-                        and an.frac.get(name, 1.0) < 1.0))
+                        and an.frac.get(name, 1.0) < 1.0)
+               and ("hw" not in spec or v.kind == "act"))
     fuse_in = None
     if fusable:
         in_edges = g.in_edges(name)
@@ -563,6 +684,29 @@ def apply_vertex_fused(v, ins, params, x, analysis: PlanAnalysis, *,
     else:                       # act
         out = SC.act_relu(xin, c=an.out_shape[v.name][1], **kw)
     return out if want_payload else (out, None)
+
+
+def line_buffers(g: Graph, *, use_pallas: bool) -> dict[str, dict]:
+    """Per k x k conv vertex of a spatial graph: the body that runs it,
+    and for the line-buffer kernel its image rows per row block, the halo
+    rows each block reads again (``k // 2`` above and below) and the halo
+    bytes re-read per frame; beside them the DSE's Eq. 1 depth of the
+    vertex's line buffer (``k * W * cin`` words, ``Vertex.base_depth``)."""
+    out = {}
+    for v in g.vertices():
+        spec = v.meta.get("exec", {})
+        if v.kind != "conv" or "hw" not in spec or spec["k"] == 1:
+            continue
+        (h, w), k = spec["hw"], spec["k"]
+        rec = {"kernel": "xla", "eq1_depth_words": v.base_depth}
+        if use_pallas and not xla_conv(spec):
+            rows, _, _ = SC.kxk_tiles(h, w, k, spec["cin"], spec["cout"])
+            rec.update(kernel="smof_conv_kxk", rows_per_block=rows,
+                       halo_rows=2 * (k // 2),
+                       halo_bytes_per_frame=SC.kxk_halo_bytes(
+                           h, w, k, spec["cin"], spec["cout"]))
+        out[v.name] = rec
+    return out
 
 
 def run_vertices(g: Graph, an: PlanAnalysis, names: list[str], params: dict,

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable
 
 import jax
@@ -43,10 +44,10 @@ from ...memory import ChannelConfig, MemoryModel, build_memory_model
 from ...obs.modelcheck import ModelCheck, check_stream
 from ...obs.stream import StreamTracer
 from ...obs.trace import NULL_RECORDER, scope
-from ..executor import (BFP8_BLOCK, TEMPORAL_KINDS, PlanAnalysis, SpillReport,
-                        _exec_spec, _make_offchip_hop, analyze_plan,
-                        bfp8_spill_decode, bfp8_spill_encode, init_params,
-                        resolve_kernel_mode, run_vertices)
+from ..executor import (BFP8_BLOCK, PlanAnalysis, SpillReport, _exec_spec,
+                        _make_offchip_hop, analyze_plan, bfp8_spill_decode,
+                        bfp8_spill_encode, init_params, resolve_kernel_mode,
+                        run_vertices, weight_shape)
 from . import queues as Q
 from . import schedule as SCH
 
@@ -269,7 +270,8 @@ def _make_stage_fns(g: Graph, an: PlanAnalysis, names: list[list[str]],
 class StreamingExecutor:
     """A jitted pipelined form of one ExecutionPlan.
 
-    ``fn(params, xs)`` maps a ``(B, m, c)`` microbatch stream to ``(B, L)``
+    ``fn(params, xs)`` maps a ``(B,) + frame`` microbatch stream (a frame
+    is ``(m, c)``, or ``(H, W, c)`` for a spatial graph) to ``(B, L)``
     outputs, bit-for-bit the outputs of running the sequential executor on
     each microbatch independently (modulo nothing: the same codecs run in
     the same composition).  ``stage_fns`` are the individually-jitted
@@ -420,11 +422,8 @@ def stage_weight_bits(g: Graph, an: PlanAnalysis) -> dict[int, int]:
     out = {j: 0 for j in range(an.n_stages)}
     for name, f in an.frac.items():
         v = g.vertex(name)
-        spec = _exec_spec(g, name)
-        if v.kind in TEMPORAL_KINDS:
-            wbits = spec.get("taps", 3) * spec["cout"] * v.weight_bits
-        else:
-            wbits = spec["cin"] * spec["cout"] * v.weight_bits
+        wbits = (math.prod(weight_shape(v.kind, _exec_spec(g, name)))
+                 * v.weight_bits)
         out[an.stage_of[name]] += int(round((1.0 - f) * wbits))
     return out
 

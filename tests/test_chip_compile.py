@@ -3,7 +3,7 @@
 Nothing runs here: every kernel is lowered and compiled by the TPU compiler
 for a described ``v5e:2x2`` topology (no chip attached), at the widths
 ``chip_smoke.py`` drives — the UNet frame of 368x480 positions and its
-64..1024 channel ladder.  Interpret mode cannot show what Mosaic refuses
+64..1024 channel ladder — and the published 2-D UNet's k x k convs.  Interpret mode cannot show what Mosaic refuses
 (unsupported reshapes, value-level dynamic slices, unaligned tiles); this
 file does, at no chip time.
 
@@ -80,6 +80,17 @@ def test_conv2d_compiles(shape_of, variant, m, cin, cout):
     else:
         _compile(lambda x, w: SC.conv2d(x, w, encode=encode),
                  shape_of((m, cin)), w)
+
+
+# (h, w, cin, cout): the published UNet's first level at full resolution,
+# and its widest conv four pools down (W = 60 walks rows padded to 64)
+KXK_CASES = [(368, 480, 64, 64), (46, 60, 512, 1024)]
+
+
+@pytest.mark.parametrize("h,w,cin,cout", KXK_CASES)
+def test_conv_kxk_compiles(shape_of, h, w, cin, cout):
+    _compile(lambda x, wt: SC.conv_kxk(x, wt, hw=(h, w)),
+             shape_of((h * w, cin)), shape_of((3, 3, cin, cout)))
 
 
 def test_conv2d_normalised_tile_compiles(shape_of):

@@ -183,9 +183,9 @@ class TestReport:
 
 class TestLoweringErrors:
     def test_non_exec_graph_rejected(self):
-        from repro.core import build_unet
+        from repro.core import build_yolov8n
         with pytest.raises(ValueError, match="exec"):
-            reference_pipeline(build_unet())
+            reference_pipeline(build_yolov8n())
 
     def test_unknown_codec_rejected(self):
         g = build_unet_exec()

@@ -505,3 +505,76 @@ class TestGraphKernelConformance:
         yp = np.asarray(lower_plan(g, plan, kernel_mode="pallas",
                                    interpret=True)(x))
         np.testing.assert_array_equal(yr, yp)
+
+
+# =============================================================================
+# smof_conv_kxk — the line-buffer k x k conv over (H*W, C) stripes
+# =============================================================================
+
+def _conv_same_hwio(x, w, hw):
+    k = w.shape[0]
+    return jax.lax.conv_general_dilated(
+        x.reshape((1,) + hw + (x.shape[1],)), w, (1, 1), [(k // 2, k // 2)] * 2,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST).reshape(-1, w.shape[-1])
+
+
+def _assert_kxk_close(got, x, w, hw):
+    """The kernel sums each output's ``k*k*cin`` products tap by tap, XLA's
+    conv in its own order: they agree to the float32 reassociation bound
+    ``2 * K * eps * sum |x w|``, K = k*k*cin."""
+    want = _conv_same_hwio(x, w, hw)
+    bound = (2 * w.shape[0] ** 2 * w.shape[2] * np.finfo(np.float32).eps
+             * np.asarray(_conv_same_hwio(jnp.abs(x), jnp.abs(w), hw)))
+    diff = np.abs(np.asarray(got) - np.asarray(want))
+    assert np.all(diff <= bound + 1e-30), (diff.max(), bound.max())
+
+
+class TestConvKxK:
+    """``streaming_conv.conv_kxk`` in interpret mode against XLA's conv:
+    odd image widths (the kernel pads rows to 8), a height of 261 rows
+    that takes several row blocks (for k = 3 two of 132, which 261 is
+    no multiple of), the RGB
+    stem's 3 channels and widths off the 128 lanes, k of 1 and 3."""
+
+    @pytest.mark.parametrize("k", [1, 3])
+    @pytest.mark.parametrize("cin", [3, 64, 96])
+    @pytest.mark.parametrize("h,w", [(261, 13), (6, 8)])
+    def test_matches_lax_conv(self, h, w, cin, k):
+        from repro.kernels import streaming_conv as SC
+        kx, kw = jax.random.split(jax.random.PRNGKey(h * w + cin + k))
+        x = jax.random.normal(kx, (h * w, cin), jnp.float32)
+        wt = jax.random.normal(kw, (k, k, cin, 40), jnp.float32)
+        rows = SC.kxk_tiles(h, w, k, cin, 40)[0]
+        if h == 261:        # k = 3: 2 blocks of 132 rows; k = 1: 3 of 87
+            assert rows < h and (h % rows or k == 1), rows
+        got = SC.conv_kxk(x, wt, hw=(h, w), interpret=True)
+        assert got.shape == (h * w, 40)
+        _assert_kxk_close(got, x, wt, (h, w))
+
+    def test_wide_cout_tiles_the_channels(self):
+        """cout above ``KXK_BC`` runs in channel blocks (300 -> 2 x 256,
+        padded), each reusing the row block's shifted copies."""
+        from repro.kernels import streaming_conv as SC
+        kx, kw = jax.random.split(jax.random.PRNGKey(3))
+        x = jax.random.normal(kx, (5 * 8, 16), jnp.float32)
+        wt = jax.random.normal(kw, (3, 3, 16, 300), jnp.float32)
+        assert SC.kxk_tiles(5, 8, 3, 16, 300)[2] == SC.KXK_BC
+        got = SC.conv_kxk(x, wt, hw=(5, 8), interpret=True)
+        _assert_kxk_close(got, x, wt, (5, 8))
+
+    @pytest.mark.parametrize("h,w,cin,cout", [
+        (368, 480, 64, 64), (184, 240, 128, 128), (92, 120, 256, 256),
+        (46, 60, 512, 512), (23, 30, 1024, 1024)])
+    def test_tiles_of_the_unet_levels(self, h, w, cin, cout):
+        """At each published UNet level the row block is a whole number of
+        halo blocks, its input fits the block budget, and it pads the
+        image by fewer rows than one block."""
+        from repro.kernels import streaming_conv as SC
+        rows, wp, bc = SC.kxk_tiles(h, w, 3, cin, cout)
+        assert rows % 2 == 0 and wp % 8 == 0 and wp - w < 8
+        assert rows * wp * max(cin, 128) * 4 <= SC.KXK_BLOCK_BYTES
+        assert -(-h // rows) * rows - h < rows
+        assert bc == min(cout, SC.KXK_BC)
+        assert SC.kxk_halo_bytes(h, w, 3, cin, cout) == (
+            -(-h // rows) * 2 * wp * cin * 4)

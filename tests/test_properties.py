@@ -573,6 +573,19 @@ def _sc_case(m, c, cout, seed):
     return x, w
 
 
+def _assert_reordered_sum(a, b, x, w):
+    """``a`` and ``b`` are the same ``x @ w`` up to the order of each
+    output's float32 sum.  A tile never changes which products an output
+    sums (the full K axis per grid step), but XLA's CPU dot picks its
+    kernel, and with it the summation order, by the operands' shapes, so
+    off a TPU two tilings may differ by reassociation: at most
+    ``2 * K * eps * sum_k |x_k w_k|`` an output."""
+    x, w = np.asarray(x, np.float64), np.asarray(w, np.float64)
+    bound = 2 * x.shape[1] * np.finfo(np.float32).eps * (np.abs(x) @ np.abs(w))
+    diff = np.abs(np.asarray(a, np.float64) - np.asarray(b, np.float64))
+    assert np.all(diff <= bound), (diff.max(), bound.min())
+
+
 def _sc_encode_ref(y, block=32):
     import jax.numpy as jnp
     from repro.kernels import ref as kref
@@ -586,9 +599,12 @@ def _sc_encode_ref(y, block=32):
        st.integers(0, 2 ** 31 - 1))
 @settings(max_examples=20, deadline=None)
 def test_fused_conv_codec_equals_unfused_pipeline(m, c, cout, seed):
-    """decode->conv->encode fused inside one pallas_call is *bitwise* the
-    three-dispatch pipeline, for ANY shape: same activation, same quant
-    blocks, same payload bytes."""
+    """decode->conv->encode fused inside one pallas_call is the
+    three-dispatch pipeline, for ANY shape: the same activation up to the
+    order of its sums (``_assert_reordered_sum``: the fused kernel pads
+    the output channels to the codec block, and the CPU's dot sums a
+    wider operand in another order), and the payload bitwise the
+    encoding of the activation it emits."""
     import jax
     from repro.kernels import ref as kref
     from repro.kernels import streaming_conv as SC
@@ -598,14 +614,12 @@ def test_fused_conv_codec_equals_unfused_pipeline(m, c, cout, seed):
     y_f, pay_f = SC.conv2d(None, w, payload=payload, encode=True,
                            interpret=True)
 
-    def unfused(payload):
-        xe = kref.bfp8_dequant_ref(*payload, block=32)[:, :c]
-        y = kref.conv2d_ref(xe, w)
-        return y, _sc_encode_ref(y)
-    y_u, pay_u = jax.jit(unfused)(payload)
-    np.testing.assert_array_equal(np.asarray(y_f), np.asarray(y_u))
-    np.testing.assert_array_equal(np.asarray(pay_f[0]), np.asarray(pay_u[0]))
-    np.testing.assert_array_equal(np.asarray(pay_f[1]), np.asarray(pay_u[1]))
+    xe = kref.bfp8_dequant_ref(*payload, block=32)[:, :c]
+    y_u = jax.jit(kref.conv2d_ref)(xe, w)
+    _assert_reordered_sum(y_f, y_u, xe, w)
+    pay_y = jax.jit(_sc_encode_ref)(y_f)
+    np.testing.assert_array_equal(np.asarray(pay_f[0]), np.asarray(pay_y[0]))
+    np.testing.assert_array_equal(np.asarray(pay_f[1]), np.asarray(pay_y[1]))
 
 
 @given(st.integers(1, 70), st.integers(1, 70), st.integers(1, 48),
@@ -614,13 +628,14 @@ def test_fused_conv_codec_equals_unfused_pipeline(m, c, cout, seed):
 @settings(max_examples=20, deadline=None)
 def test_conv_tile_size_independence(m, c, cout, bm, bc, seed):
     """Any (bm, bc) draw — dividing the axes or not, bigger than them or
-    not — produces bit-identical results to the default tiling."""
+    not — sums the same products as the default tiling, so the results
+    agree up to the order of each sum (``_assert_reordered_sum``)."""
     from repro.kernels import streaming_conv as SC
 
     x, w = _sc_case(m, c, cout, seed)
     base = SC.conv2d(x, w, interpret=True)
     tiled = SC.conv2d(x, w, bm=bm, bc=bc, interpret=True)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(tiled))
+    _assert_reordered_sum(base, tiled, x, w)
 
 
 @given(st.integers(1, 60), st.integers(1, 60), st.integers(1, 128),

@@ -65,9 +65,9 @@ class TestRegistry:
         assert exec_input_shape(g) == (32, 32)
 
     def test_paper_graph_has_no_exec_shape(self):
-        from repro.core import build_unet
+        from repro.core import build_yolov8n
         with pytest.raises(ValueError, match="exec"):
-            exec_input_shape(build_unet())
+            exec_input_shape(build_yolov8n())
 
 
 # =============================================================================
