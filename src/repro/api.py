@@ -326,8 +326,9 @@ class Compiled:
         SpillReport (staged) / StreamReport (pipelined) summaries under
         ``traffic``, plan provenance, and — when the autotuner ran — its
         summary incl. the CalibrationReport.  A spatial graph adds
-        ``line_buffers``: per k x k conv, the kernel's row blocks and the
-        halo it reads again, beside the DSE's Eq. 1 depth
+        ``line_buffers``: per k x k conv, the kernel's layout (taps per
+        dot, dot depth), row blocks and the halo it reads again, beside
+        the DSE's Eq. 1 depth
         (``runtime.executor.line_buffers``)."""
         out = {
             "model": self.model,

@@ -519,6 +519,17 @@ def act_relu(x, *, c: int | None = None, payload=None, encode=False,
 KXK_BLOCK_BYTES = 2 * 2 ** 20
 KXK_VMEM_LIMIT = 64 * 2 ** 20       # scoped VMEM the kernel may claim
 KXK_BC = 256                        # out-channel block of a wide conv
+#: a conv whose whole contraction ``k*k*cin`` is at most this deep fits one
+#: 128-deep MXU pass, so all its k*k taps go into one dot
+KXK_FULL_TAP_K = 128
+
+
+def kxk_full_tap(k: int, cin: int) -> bool:
+    """Whether :func:`conv_kxk` takes the full-tap layout (all ``k*k`` taps
+    of a ``cin``-deep input side by side, one dot of depth ``k*k*cin``)
+    rather than the line-buffer kernel's row-tap one (``k`` dots of depth
+    ``k*cin`` a step)."""
+    return k * k * cin <= KXK_FULL_TAP_K
 
 
 @functools.lru_cache(maxsize=None)
@@ -606,6 +617,15 @@ def conv_kxk(x, w, *, hw: tuple[int, int], interpret: bool = False):
     taps shift within a row in VMEM, mask the row's edges and sit side by
     side, so a row of taps is one dot of depth ``k * cin``.
 
+    A conv whose whole contraction ``k*k*cin`` fits one 128-deep MXU pass
+    (:func:`kxk_full_tap`: the RGB stem, 27 deep) takes the full-tap layout
+    instead: the ``k*k`` shifted copies of the zero-padded image side by
+    side, ``(H*W, k*k*cin)``, and one dot of that depth, both XLA's.  Each
+    of the kernel's ``k`` dots would take a whole pass (several at
+    ``HIGHEST``) for ``k * cin`` lanes, and XLA keeps the copies
+    position-minor, lane-dense, where the kernel's copies of ``cin`` lanes
+    pad to 128 (docs/KERNELS.md has the chip's readings of both).
+
     Tile contract: ``rows`` is a multiple of ``2 * (k // 2)``; the row
     width walked is ``W`` padded to a multiple of 8 (a row of zeros past
     the edge, sliced off after); ``bc`` is ``cout`` up to ``KXK_BC``,
@@ -621,6 +641,14 @@ def conv_kxk(x, w, *, hw: tuple[int, int], interpret: bool = False):
     assert k == k2 and k % 2 == 1, w.shape
     assert x.shape == (h * wd, cin), (x.shape, hw, w.shape)
     p = k // 2
+    if kxk_full_tap(k, cin):
+        x3 = jnp.pad(x.reshape(h, wd, cin), ((p, p), (p, p), (0, 0)))
+        taps = [x3[dy:dy + h, dx:dx + wd]
+                for dy in range(k) for dx in range(k)]
+        return jnp.dot(jnp.concatenate(taps, axis=-1).reshape(h * wd, -1),
+                       w.reshape(k * k * cin, cout),
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
     rows, wp, bc = kxk_tiles(h, wd, k, cin, cout)
     hp = _round_up(h, rows)
     npad = _round_up(cout, bc)
@@ -656,4 +684,4 @@ def conv_kxk(x, w, *, hw: tuple[int, int], interpret: bool = False):
 
 
 __all__ = ["conv2d", "dwconv", "pool", "act_relu", "conv_kxk", "kxk_tiles",
-           "DEFAULT_BM", "DEFAULT_BC"]
+           "kxk_full_tap", "DEFAULT_BM", "DEFAULT_BC"]

@@ -59,8 +59,8 @@ hop see the same ``(m, c)`` stripes as above.  Its weights are HWIO
   input      flatten the ``(H, W, C)`` frame to its stripe
   conv       k x k 'same' conv, stride 1 (``streaming_conv.conv_kxk``,
              the line-buffer kernel; a conv whose whole contraction
-             ``k*k*cin`` fits one 128-deep MXU pass, the RGB stem, runs
-             as XLA's conv); k = 1 is a matmul
+             ``k*k*cin`` fits one 128-deep MXU pass, the RGB stem, takes
+             all its taps in one dot: XLA's im2col); k = 1 is a matmul
   deconv     k = stride transposed conv: one matmul to ``k*k*cout``
              channels, then depth-to-space
   pool       k = stride max pool (XLA)
@@ -71,8 +71,8 @@ spatial conv, deconv and matmul is a float32 one (``HIGHEST``): through
 the published UNet's 23 convs, a bfloat16 pass makes any difference in
 the order of a sum grow into bfloat16 rounding noise (PERF.md, section 6),
 so the program could not be checked against a reference.  The matmuls
-(k = 1, deconvs) and the stem are XLA's; a fragmented spatial weight is
-refused (ROADMAP B1), and only act fuses the BFP8 codec.
+(k = 1, deconvs) and the stem's one dot are XLA's; a fragmented spatial
+weight is refused (ROADMAP B1), and only act fuses the BFP8 codec.
 
 The lowering also emits a :class:`SpillReport`: per evicted/boundary edge,
 the raw and off-chip bit volumes.  For BFP8 the off-chip volume is computed
@@ -104,9 +104,6 @@ from ..kernels.streamed_matmul import (_round_up, splits_weight,
 from ..obs.trace import scope
 
 WEIGHT_KINDS = ("conv", "deconv", "matmul")
-#: a k x k conv whose whole contraction is at most this deep runs as XLA's
-#: conv: the Pallas kernel would feed the MXU ``cin`` lanes a tap
-XLA_CONV_MAX_K = 128
 TEMPORAL_KINDS = ("dwconv",)
 LOSSLESS_CODECS = ("none", "rle", "huffman")
 BFP8_BLOCK = 32
@@ -290,15 +287,9 @@ def _dwconv(x: jax.Array, w: jax.Array) -> jax.Array:
     return sum(w[k][None, :] * xp[k:k + m] for k in range(taps))
 
 
-def xla_conv(spec: dict) -> bool:
-    """Whether a spatial k x k conv runs as XLA's conv, not the line-buffer
-    kernel: its whole contraction fits one MXU pass (the RGB stem)."""
-    return spec["k"] ** 2 * spec["cin"] <= XLA_CONV_MAX_K
-
-
 def _conv_same(x: jax.Array, w: jax.Array, hw: tuple[int, int]) -> jax.Array:
     """k x k 'same' conv of a ``(H*W, cin)`` stripe by XLA, HWIO ``w``,
-    float32 products."""
+    float32 products: the reference-mode body of a spatial conv."""
     k = w.shape[0]
     y = jax.lax.conv_general_dilated(
         x.reshape((1,) + tuple(hw) + (x.shape[1],)), w, (1, 1),
@@ -582,7 +573,7 @@ def _apply_spatial(v, spec: dict, ins, params, x, an: PlanAnalysis):
             f"{v.name}: the plan fragments a spatial {v.kind}'s weight; "
             f"only 1-D graphs stream weights (ROADMAP B1)")
     if v.kind == "conv" and spec["k"] > 1:
-        if an.use_pallas and not xla_conv(spec):
+        if an.use_pallas:
             return SC.conv_kxk(ins[0], w, hw=tuple(spec["hw"]),
                                interpret=an.interpret)
         return _conv_same(ins[0], w, tuple(spec["hw"]))
@@ -606,15 +597,16 @@ def vertex_body(g: Graph, name: str, an: PlanAnalysis) -> str:
     ``"reference"``.  Pallas mode still runs reference bodies for the
     data-movement kinds and for a fragmented weight too small to split
     (``streamed_matmul_padded``'s plain-dot fallback), and for a spatial
-    graph's max pools, matmuls and XLA-run stem — this names them, so a
-    silent fallback shows up in a script's output."""
+    graph's max pools, matmuls and the stem's im2col dot — this names
+    them, so a silent fallback shows up in a script's output."""
     v = g.vertex(name)
     spec = _exec_spec(g, name)
     if not an.use_pallas or v.kind not in FUSABLE_KINDS:
         return "reference"
     if "hw" in spec and v.kind != "act":
         return ("pallas" if v.kind == "conv" and spec["k"] > 1
-                and not xla_conv(spec) else "reference")
+                and not SC.kxk_full_tap(spec["k"], spec["cin"])
+                else "reference")
     if v.kind in WEIGHT_KINDS and an.frac.get(name, 1.0) < 1.0:
         return ("pallas" if splits_weight(_exec_spec(g, name)["cin"])
                 else "reference")
@@ -687,24 +679,31 @@ def apply_vertex_fused(v, ins, params, x, analysis: PlanAnalysis, *,
 
 
 def line_buffers(g: Graph, *, use_pallas: bool) -> dict[str, dict]:
-    """Per k x k conv vertex of a spatial graph: the body that runs it,
-    and for the line-buffer kernel its image rows per row block, the halo
-    rows each block reads again (``k // 2`` above and below) and the halo
-    bytes re-read per frame; beside them the DSE's Eq. 1 depth of the
-    vertex's line buffer (``k * W * cin`` words, ``Vertex.base_depth``)."""
+    """Per k x k conv vertex of a spatial graph: the body that runs it
+    and, in pallas mode, its layout (``streaming_conv.conv_kxk``): the
+    taps each dot takes and its depth, ``k*k`` taps for XLA's im2col, ``k``
+    for the line-buffer kernel, and for the kernel its image rows per row
+    block, the halo rows each block reads again (``k // 2`` above and
+    below) and the halo bytes re-read per frame; beside them the DSE's
+    Eq. 1 depth of the vertex's line buffer (``k * W * cin`` words,
+    ``Vertex.base_depth``)."""
     out = {}
     for v in g.vertices():
         spec = v.meta.get("exec", {})
         if v.kind != "conv" or "hw" not in spec or spec["k"] == 1:
             continue
-        (h, w), k = spec["hw"], spec["k"]
+        (h, w), k, cin = spec["hw"], spec["k"], spec["cin"]
         rec = {"kernel": "xla", "eq1_depth_words": v.base_depth}
-        if use_pallas and not xla_conv(spec):
-            rows, _, _ = SC.kxk_tiles(h, w, k, spec["cin"], spec["cout"])
-            rec.update(kernel="smof_conv_kxk", rows_per_block=rows,
+        if use_pallas and SC.kxk_full_tap(k, cin):
+            rec.update(kernel="xla_im2col", taps_per_dot=k * k,
+                       dot_depth=k * k * cin)
+        elif use_pallas:
+            rows, _, _ = SC.kxk_tiles(h, w, k, cin, spec["cout"])
+            rec.update(kernel="smof_conv_kxk", taps_per_dot=k,
+                       dot_depth=k * cin, rows_per_block=rows,
                        halo_rows=2 * (k // 2),
                        halo_bytes_per_frame=SC.kxk_halo_bytes(
-                           h, w, k, spec["cin"], spec["cout"]))
+                           h, w, k, cin, spec["cout"]))
         out[v.name] = rec
     return out
 

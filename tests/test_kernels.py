@@ -530,12 +530,43 @@ def _assert_kxk_close(got, x, w, hw):
     assert np.all(diff <= bound + 1e-30), (diff.max(), bound.max())
 
 
+def _kxk_dots(x, w, hw):
+    """``(dots, depth)`` of one output row of ``conv_kxk``: how many dots
+    it sums and how deep each is, read from what it traces (the weight
+    operand of its ``pallas_call``, else its one ``dot_general``)."""
+    from repro.kernels import streaming_conv as SC
+    eqns = jax.make_jaxpr(
+        lambda x, w: SC.conv_kxk(x, w, hw=hw, interpret=True))(x, w).eqns
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    if calls:
+        [call] = calls
+        return call.invars[-1].aval.shape[:2]
+    [dot] = [e for e in eqns if e.primitive.name == "dot_general"]
+    return 1, dot.invars[0].aval.shape[-1]
+
+
+@pytest.fixture
+def small_kxk_blocks(monkeypatch):
+    """``small_kxk_blocks(w, cin)`` caps ``conv_kxk``'s row blocks at four
+    image rows of a ``w``-wide, ``cin``-deep image; the tile cache is
+    cleared on both sides so no other test sees these tiles."""
+    from repro.kernels import streaming_conv as SC
+
+    def cap(w, cin):
+        monkeypatch.setattr(SC, "KXK_BLOCK_BYTES", 4 * SC._round_up(w, 8)
+                            * SC._round_up(cin, 128) * 4)
+        SC.kxk_tiles.cache_clear()
+    yield cap
+    SC.kxk_tiles.cache_clear()
+
+
 class TestConvKxK:
     """``streaming_conv.conv_kxk`` in interpret mode against XLA's conv:
     odd image widths (the kernel pads rows to 8), a height of 261 rows
     that takes several row blocks (for k = 3 two of 132, which 261 is
     no multiple of), the RGB
-    stem's 3 channels and widths off the 128 lanes, k of 1 and 3."""
+    stem's 3 channels (the full-tap layout) and widths off the 128 lanes,
+    k of 1 and 3."""
 
     @pytest.mark.parametrize("k", [1, 3])
     @pytest.mark.parametrize("cin", [3, 64, 96])
@@ -551,6 +582,29 @@ class TestConvKxK:
         got = SC.conv_kxk(x, wt, hw=(h, w), interpret=True)
         assert got.shape == (h * w, 40)
         _assert_kxk_close(got, x, wt, (h, w))
+
+    @pytest.mark.parametrize("cin,full", [(3, True), (14, True), (15, False)])
+    @pytest.mark.parametrize("h,w", [(261, 13), (6, 8)])
+    def test_full_tap_layout(self, h, w, cin, full, small_kxk_blocks):
+        """A 3 x 3 conv whose whole contraction fits one 128-deep MXU pass
+        (cin 3 and 14: 27 and 126 deep) takes all nine taps in one dot, a
+        135-deep one (cin 15) the line-buffer kernel's three dots of a row
+        of taps; each matches XLA's conv, and another row block gives the
+        same bits."""
+        from repro.kernels import streaming_conv as SC
+        kx, kw = jax.random.split(jax.random.PRNGKey(h * w + cin))
+        x = jax.random.normal(kx, (h * w, cin), jnp.float32)
+        wt = jax.random.normal(kw, (3, 3, cin, 40), jnp.float32)
+        assert SC.kxk_full_tap(3, cin) == full
+        assert _kxk_dots(x, wt, (h, w)) == ((1, 9 * cin) if full
+                                            else (3, 3 * cin))
+        rows = SC.kxk_tiles(h, w, 3, cin, 40)[0]
+        got = SC.conv_kxk(x, wt, hw=(h, w), interpret=True)
+        _assert_kxk_close(got, x, wt, (h, w))
+        small_kxk_blocks(w, cin)
+        assert SC.kxk_tiles(h, w, 3, cin, 40)[0] not in (rows, h)
+        np.testing.assert_array_equal(
+            got, SC.conv_kxk(x, wt, hw=(h, w), interpret=True))
 
     def test_wide_cout_tiles_the_channels(self):
         """cout above ``KXK_BC`` runs in channel blocks (300 -> 2 x 256,

@@ -94,8 +94,9 @@ def _rel_l2(net, weights, frames, ys, arith):
 
 
 def test_dse_plan_matches_reference(case):
-    """The DSE's plan (one stage at this size), every k x k conv through
-    the line-buffer kernel but the RGB stem."""
+    """The DSE's plan (one stage at this size), the RGB stem with its nine
+    taps in one dot (XLA's im2col), every other k x k conv through the
+    line-buffer kernel, a row of three taps a dot."""
     net, weights, frames = case
     c = _compile(net, weights)
     assert c.plan.n_stages == 1
@@ -104,9 +105,10 @@ def test_dse_plan_matches_reference(case):
     assert ys.shape == (B, 32 * 48 * 32)
     assert max(_rel_l2(net, weights, frames, ys, ARITH)) < TOL
     lb = c.report()["line_buffers"]
-    assert lb["conv_2"]["kernel"] == "xla"            # 3 x 3 x 3 = 27 deep
-    assert all(r["kernel"] == "smof_conv_kxk" for n, r in lb.items()
-               if n != "conv_2")
+    assert (lb["conv_2"]["kernel"], lb["conv_2"]["taps_per_dot"],
+            lb["conv_2"]["dot_depth"]) == ("xla_im2col", 9, 27)  # 3 x 3 x 3
+    assert all((r["kernel"], r["taps_per_dot"]) == ("smof_conv_kxk", 3)
+               for n, r in lb.items() if n != "conv_2")
     r = lb["conv_4"]                                  # 32 x 48, 32 -> 32
     assert (r["halo_rows"], r["eq1_depth_words"]) == (2, 3 * 48 * 32)
     assert r["halo_bytes_per_frame"] == (
