@@ -38,15 +38,6 @@ def run() -> None:
              f"hbm_traffic_saving={1 - traffic_frag / traffic_full:.2f} "
              f"vmem_claim_mb={vmem_bytes(ks, N, 128, 128, 128) / 2 ** 20:.1f}")
 
-    # flash attention ref
-    q, k, v = (jax.random.normal(kk, (1, 1024, 4, 64), jnp.float32)
-               for kk in jax.random.split(key, 3))
-    fa = jax.jit(lambda a, b, c: ref.flash_attention_ref(a, b, c, causal=True))
-    fa(q, k, v).block_until_ready()
-    us = timeit(lambda: fa(q, k, v).block_until_ready())
-    emit("kernel/flash_attention_ref_1k", us,
-         f"flops={4 * 1024 * 1024 * 4 * 64 / 2 / 1e9:.2f}G")
-
     # bfp8 codec
     xx = jax.random.normal(key, (1024, 1024), jnp.float32)
     qf = jax.jit(lambda a: ref.bfp8_quant_ref(a))

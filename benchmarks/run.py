@@ -7,7 +7,7 @@ CSV output schema (one line per benchmark point, written to stdout):
 
   name          ``<section>/<point>`` — section matches the paper artefact
                 (``table3``, ``table4``, ``table5``, ``fig6``, ``fig7``,
-                ``fig8``, ``kernels``, ``roofline``), ``e2e`` for the
+                ``fig8``, ``kernels``), ``e2e`` for the
                 executed-pipeline benchmark, or ``autotune`` for the
                 closed-loop candidate trajectory (``--autotune``).
   us_per_call   median wall-clock microseconds of the timed callable
@@ -37,10 +37,6 @@ Modes:
                                         # regression gate: exits 1 if any
                                         # row breaks the per-metric
                                         # tolerances (benchmarks/baseline.py)
-
-The roofline section reads the dry-run artifacts in results/dryrun (run
-``python -m repro.launch.dryrun --all`` first; checked-in results are used
-if present) — see README.md § "Benchmarks" for the full workflow.
 """
 from __future__ import annotations
 
@@ -82,7 +78,7 @@ def main(argv: list[str] | None = None) -> None:
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
     from . import (baseline, e2e_executor, fig6_ablation, fig7_compression,
-                   fig8_variability, kernels_bench, roofline, table3_models,
+                   fig8_variability, kernels_bench, table3_models,
                    table4_partitioning, table5_throughput)
     print("name,us_per_call,derived")
     table3_models.run()
@@ -117,13 +113,6 @@ def main(argv: list[str] | None = None) -> None:
     fig8_variability.run()
     table5_throughput.run()
     kernels_bench.run()
-    try:
-        roofline.run()
-    except FileNotFoundError:
-        print("roofline,0,skipped (needs results/dryrun artifacts: run "
-              "`python -m repro.launch.dryrun --all` first — see README.md "
-              "§ Benchmarks)",
-              file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -1,17 +1,8 @@
 """End-to-end serving driver (SMOF is an inference toolflow).
 
-Serves a reduced-config model with batched requests through the continuous-
-batching engine: prefill per request, lockstep decode over slots, KV pages
-evicted to host in BFP8 when requests finish (the paper's activation
-eviction at the HBM<->host level).
-
-    PYTHONPATH=src python examples/serve_batched.py --arch yi-6b \
-        --requests 6 --max-new 12
-
-Passing ``--model`` switches to the streaming-graph serving path instead:
-an ``EXEC_MODELS`` graph is compiled through ``repro.compile`` (the same
-``--channel``/``--channel-gbps`` knobs as quickstart, docs/MEMORY.md) and
-frames are served through ``Compiled.serve``; the summary prints the
+Compiles an ``EXEC_MODELS`` streaming graph through ``repro.compile`` (the
+same ``--channel``/``--channel-gbps`` knobs as quickstart, docs/MEMORY.md)
+and serves frames through ``Compiled.serve``; the summary prints the
 off-chip per-stream bandwidth table and prefetch deadline misses:
 
     PYTHONPATH=src python examples/serve_batched.py --model unet_exec \
@@ -21,15 +12,10 @@ import argparse
 import dataclasses
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.api import add_compile_args, spec_from_args
-from repro.configs import ARCHS
 from repro.core import EXEC_MODELS
-from repro.models import init_params
-from repro.serving.engine import ServingEngine
 
 
 def serve_graph(args) -> None:
@@ -76,57 +62,15 @@ def serve_graph(args) -> None:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-6b", choices=sorted(ARCHS))
     ap.add_argument("--requests", type=int, default=6)
-    ap.add_argument("--max-new", type=int, default=12)
-    ap.add_argument("--batch-slots", type=int, default=3)
-    ap.add_argument("--evict", action="store_true", default=True)
-    # --model flips to the streaming-graph path; brings --device/--mode/
-    # --channel/--channel-gbps along (docs/MEMORY.md)
-    add_compile_args(ap, default_model=None, default_mode="pipelined",
+    # brings --model/--device/--mode/--channel/--channel-gbps along
+    # (docs/MEMORY.md)
+    add_compile_args(ap, default_model="unet_exec", default_mode="pipelined",
                      models=EXEC_MODELS, modes=("staged", "pipelined"))
     ap.add_argument("--onchip-kbits", type=float, default=None,
-                    help="graph path: shrink the on-chip view so the DSE "
-                         "evicts/streams (as in quickstart)")
-    args = ap.parse_args()
-
-    if args.model is not None:
-        serve_graph(args)
-        return
-
-    cfg = ARCHS[args.arch].reduced()
-    print(f"serving {cfg.name} ({cfg.n_layers}L d={cfg.d_model}) "
-          f"with {args.batch_slots} decode slots")
-    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    eng = ServingEngine(cfg, params, max_batch=args.batch_slots, s_max=128,
-                        evict_to_host=args.evict)
-
-    rng = np.random.default_rng(0)
-    reqs = []
-    for i in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab, size=8 + 4 * (i % 3))
-        reqs.append(eng.submit(prompt, max_new_tokens=args.max_new))
-
-    t0 = time.time()
-    eng.run_until_drained()
-    dt = time.time() - t0
-
-    st = eng.stats
-    print(f"\ndrained in {dt:.2f}s")
-    print(f"  prefills      : {st.prefills}")
-    print(f"  decode steps  : {st.decode_steps} "
-          f"(continuous batching: {st.generated} tokens through "
-          f"{args.batch_slots} slots)")
-    print(f"  tokens/s      : {st.generated / dt:.1f}")
-    if st.evicted_pages:
-        print(f"  evicted pages : {st.evicted_pages} "
-              f"({st.evicted_bytes_raw / 1e6:.2f} MB -> "
-              f"{st.evicted_bytes_compressed / 1e6:.2f} MB, "
-              f"ratio {st.evicted_bytes_compressed / st.evicted_bytes_raw:.2f}"
-              f" — paper Eq. 2's c_bar)")
-    for r in reqs[:3]:
-        print(f"  req {r.rid}: {len(r.out_tokens)} tokens "
-              f"{r.out_tokens[:8]}...")
+                    help="shrink the on-chip view so the DSE evicts/streams "
+                         "(as in quickstart)")
+    serve_graph(ap.parse_args())
 
 
 if __name__ == "__main__":

@@ -1,7 +1,7 @@
 """Layer-graph IR for SMOF.
 
-The CNN / LM workload is abstracted to a DAG (paper §III-A): vertices are
-operations (conv, pool, matmul, attention, ...) and edges are data streams
+The CNN workload is abstracted to a DAG (paper §III-A): vertices are
+operations (conv, pool, matmul, ...) and edges are data streams
 between them.  Every quantity the SMOF cost models need lives here:
 
 * per-vertex: work (MACs), weight footprint, streaming rates, parallelism,
@@ -27,10 +27,9 @@ import networkx as nx
 OP_KINDS = (
     "input", "output",
     "conv", "dwconv", "deconv", "pool", "upsample", "act", "norm",
-    "add", "mul", "concat", "split", "matmul", "attention", "kv_append",
-    "router", "expert", "ssm_scan", "embed", "reshape",
+    "add", "mul", "concat", "split", "matmul", "reshape",
 )
-WEIGHTY = {"conv", "dwconv", "deconv", "matmul", "expert", "embed", "norm", "ssm_scan"}
+WEIGHTY = {"conv", "dwconv", "deconv", "matmul", "norm"}
 
 
 @dataclasses.dataclass

@@ -4,7 +4,7 @@ The DSE (core/dse.py) reasons about an abstract device; this module projects
 its decisions onto concrete knobs the JAX runtime understands:
 
 * partition list      -> staged-executor stages / PP stage boundaries
-* eviction decisions  -> which long-lived streams (KV cache, encoder output,
+* eviction decisions  -> which long-lived streams (skip activations,
                          1F1B stashes) are offloaded + their codec
 * fragmentation m     -> per-layer static VMEM fraction for the
                          ``streamed_matmul`` kernel / host weight streaming

@@ -12,8 +12,7 @@ abstraction and provide two families of instances:
     - ``TPU_V5E_RUNTIME``: on-chip = HBM,  off-chip = host DRAM over PCIe
       (staged-executor / offload level).
 
-Everything is per *device*; the distributed runtime multiplies by mesh size
-and adds ICI terms separately (see launch/ and benchmarks/roofline.py).
+Everything is per *device*.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """BFP8 quant / dequant Pallas kernels — the paper's §V-A block-floating-
 point format as the on-device eviction codec.
 
-Evicted streams (KV pages, skip activations, fragmented weight panels) pass
+Evicted streams (skip activations, fragmented weight panels) pass
 through these before crossing the HBM<->host boundary: 16-bit words become
 8-bit mantissas + one shared exponent per ``block`` values, the fixed
 compile-time ratio ``(8 + 8/block)/16`` the DSE's Eq. 2/4 uses.

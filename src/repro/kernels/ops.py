@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from .bfp8 import bfp8_dequant, bfp8_quant
-from .flash_attention import flash_attention
 from .streamed_matmul import (streamed_matmul, streamed_matmul_padded,
                               vmem_bytes)
 from . import ref
@@ -127,11 +126,6 @@ def fragmented_matmul(x: jax.Array, w: jax.Array, *,
                            interpret=interpret)
 
 
-def flash_attn(q, k, v, *, causal=True, bq=256, bk=256, interpret=None):
-    return flash_attention(q, k, v, causal=causal, bq=bq, bk=bk,
-                           interpret=resolve_interpret(interpret))
-
-
 def evict_encode(x: jax.Array, *, block: int = 32, interpret=None):
     """Quantise an eviction stream to BFP8 before it leaves HBM."""
     return bfp8_quant(x, block=block, interpret=resolve_interpret(interpret))
@@ -143,9 +137,9 @@ def evict_decode(man, exp, *, block: int = 32, dtype=jnp.float32,
                         interpret=resolve_interpret(interpret))
 
 
-__all__ = ["fragmented_matmul", "flash_attn", "evict_encode", "evict_decode",
-           "streamed_matmul", "streamed_matmul_padded", "flash_attention",
-           "bfp8_quant", "bfp8_dequant", "vmem_bytes", "ref",
+__all__ = ["fragmented_matmul", "evict_encode", "evict_decode",
+           "streamed_matmul", "streamed_matmul_padded", "bfp8_quant",
+           "bfp8_dequant", "vmem_bytes", "ref",
            "resolve_interpret", "KernelEntry", "KERNEL_REGISTRY",
            "kernel_for", "fusable_kinds", "lowerable_kinds",
            "streaming_conv"]

@@ -14,20 +14,6 @@ def streamed_matmul_ref(x: jax.Array, w_static: jax.Array,
                    ).astype(x.dtype)
 
 
-def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                        *, causal: bool = True) -> jax.Array:
-    """Plain softmax attention.  q,k,v: (B, S, H, D) (kv heads pre-repeated)."""
-    B, S, H, D = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (D ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(mask[None, None], s, -2.0 ** 30)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 def conv2d_ref(x: jax.Array, w: jax.Array) -> jax.Array:
     """1x1 channel mixing (conv/matmul/deconv): y = x @ w."""
     return jnp.dot(x, w, preferred_element_type=jnp.float32

@@ -18,8 +18,7 @@ Three read paths:
   accounting read);
 * :meth:`MetricsRegistry.metrics_text` — Prometheus text exposition
   (``# HELP`` / ``# TYPE`` + samples, label values escaped per the spec),
-  what ``GraphStreamServer.metrics_text()`` / ``ServingEngine
-  .metrics_text()`` serve to a scraper.
+  what ``GraphStreamServer.metrics_text()`` serves to a scraper.
 
 :func:`parse_metrics_text` is the matching strict parser — the round-trip
 gate the tests and the CI smoke validate the exposition through (label

@@ -9,7 +9,6 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.bfp8 import (bfp8_dequant, bfp8_dequant_values,
                                 bfp8_quant, bfp8_quant_values)
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import evict_decode, evict_encode, fragmented_matmul
 from repro.kernels.streamed_matmul import streamed_matmul, vmem_bytes
 
@@ -60,52 +59,6 @@ class TestStreamedMatmul:
         a = vmem_bytes(128, 4096, 128, 128, 128)
         b = vmem_bytes(1024, 4096, 128, 128, 128)
         assert b > a
-
-
-class TestFlashAttention:
-    @pytest.mark.parametrize("S,H,D", [(256, 2, 64), (512, 4, 128)])
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_ref(self, S, H, D, causal):
-        key = jax.random.PRNGKey(3)
-        q, k, v = (jax.random.normal(key, (2, S, H, D), jnp.float32)
-                   for key in jax.random.split(key, 3))
-        got = flash_attention(q, k, v, causal=causal, bq=128, bk=128,
-                              interpret=True)
-        want = ref.flash_attention_ref(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_bfloat16(self):
-        key = jax.random.PRNGKey(4)
-        q, k, v = (jax.random.normal(k2, (1, 256, 2, 64), jnp.bfloat16)
-                   for k2 in jax.random.split(key, 3))
-        got = flash_attention(q, k, v, causal=True, bq=128, bk=128,
-                              interpret=True)
-        want = ref.flash_attention_ref(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   rtol=3e-2, atol=3e-2)
-
-    def test_block_sweep_same_answer(self):
-        key = jax.random.PRNGKey(5)
-        q, k, v = (jax.random.normal(k2, (1, 512, 2, 64), jnp.float32)
-                   for k2 in jax.random.split(key, 3))
-        outs = [np.asarray(flash_attention(q, k, v, causal=True, bq=bq,
-                                           bk=bk, interpret=True))
-                for bq in (128, 256) for bk in (128, 256)]
-        for o in outs[1:]:
-            np.testing.assert_allclose(o, outs[0], rtol=1e-5, atol=1e-5)
-
-    def test_matches_model_oracle(self):
-        """Also agrees with the model's chunked_attention (the serving path)."""
-        from repro.models.attention import chunked_attention
-        key = jax.random.PRNGKey(6)
-        q, k, v = (jax.random.normal(k2, (2, 256, 4, 64), jnp.float32)
-                   for k2 in jax.random.split(key, 3))
-        a = np.asarray(flash_attention(q, k, v, causal=True, bq=128, bk=128,
-                                       interpret=True))
-        b = np.asarray(chunked_attention(q, k, v, causal=True, chunk=128))
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
 class TestBFP8Kernel:

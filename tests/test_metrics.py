@@ -21,7 +21,7 @@ The contract under test:
 import pytest
 
 from repro.obs import parse_metrics_text
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricFamily,
+from repro.obs.metrics import (Counter, Gauge, Histogram,
                                MetricsRegistry, escape_label_value)
 
 

@@ -1,110 +1,14 @@
-"""Serving-engine KV-page evict -> restore path and the streaming front-end.
-
-Covers the satellite gaps: BFP8 page round-trip numerics across the
-HBM<->host boundary, slot refill after eviction (continuous batching), the
-``resident_limit`` budget with its oldest-first eviction ordering, and the
-``GraphStreamServer`` front-end that feeds the pipelined streamer.
-"""
+"""The ``GraphStreamServer`` front-end that feeds the pipelined streamer:
+its flush against the sequential executor and its metrics surface."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS
 from repro.core import build_unet_exec
-from repro.core.compression import bfp8_decode
 from repro.core.plan import ExecutionPlan, LayerPlan, StreamPlan
-from repro.models import init_params
 from repro.runtime.executor import lower_plan
-from repro.serving.engine import GraphStreamServer, ServingEngine
-
-
-def _engine(**kw):
-    cfg = ARCHS["yi-6b"].reduced()
-    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
-    return ServingEngine(cfg, params, max_batch=kw.pop("max_batch", 1),
-                         s_max=64, **kw)
-
-
-def _cache_page(eng, slot):
-    return {
-        "/".join(str(getattr(p, "key", p)) for p in path):
-            np.asarray(leaf[:, slot], np.float32)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(eng.cache)
-    }
-
-
-def _zero_slot(eng, slot):
-    eng.cache = jax.tree.map(lambda c: c.at[:, slot].set(0), eng.cache)
-
-
-class TestKVEvictRestore:
-    def test_bfp8_page_roundtrip_numerics(self):
-        """Host-evicted pages decode back to ~the original KV values: small
-        nonzero codec error, bounded by the 8-bit block quantisation."""
-        eng = _engine(evict_to_host=True)
-        r = eng.submit(np.arange(8), max_new_tokens=4)
-        eng.run_until_drained()
-        assert r.rid in eng.host_store
-        # retiring does not clear the slot, so the cache still holds the
-        # exact page the engine BFP8-encoded on its way out
-        before = _cache_page(eng, 0)
-        worst = 0.0
-        for name, page in before.items():
-            got = np.asarray(bfp8_decode(eng.host_store[r.rid][name]),
-                             np.float32)
-            denom = max(np.abs(page).max(), 1e-6)
-            worst = max(worst, np.abs(got - page).max() / denom)
-        assert 0.0 < worst < 0.05, worst
-
-    def test_restore_after_host_eviction(self):
-        """evict -> zero the slot -> restore: cache ~= original page."""
-        eng = _engine(evict_to_host=True)
-        r = eng.submit(np.arange(6), max_new_tokens=3)
-        eng.run_until_drained()
-        before = _cache_page(eng, 0)
-        _zero_slot(eng, 0)
-        eng.restore_request(r.rid, 0)
-        assert r.rid not in eng.host_store        # pages came back
-        after = _cache_page(eng, 0)
-        for name, page in before.items():
-            atol = 0.05 * max(np.abs(page).max(), 1e-6)
-            np.testing.assert_allclose(after[name], page, rtol=0, atol=atol)
-        assert eng.stats.restored_pages == len(before)
-
-    def test_slot_refill_after_eviction(self):
-        """Continuous batching: one slot serves many requests; every retired
-        request's pages land on the host and the slot is reused."""
-        eng = _engine(evict_to_host=True, max_batch=1)
-        rs = [eng.submit(np.arange(4) + i, max_new_tokens=3)
-              for i in range(3)]
-        eng.run_until_drained()
-        assert all(r.done for r in rs)
-        assert eng.stats.prefills == 3            # 3 requests through 1 slot
-        assert sorted(eng.host_store) == [r.rid for r in rs]
-
-    def test_budget_exceeded_eviction_ordering(self):
-        """resident_limit parks the newest retired page-sets in HBM; going
-        over budget spills the OLDEST first (retirement order)."""
-        eng = _engine(evict_to_host=True, max_batch=1, resident_limit=1)
-        rs = [eng.submit(np.arange(4) + i, max_new_tokens=3)
-              for i in range(3)]
-        eng.run_until_drained()
-        # newest stays resident, the two older crossed to host in order
-        assert list(eng.resident_store) == [rs[2].rid]
-        assert list(eng.host_store) == [rs[0].rid, rs[1].rid]
-
-    def test_restore_from_resident_is_exact(self):
-        eng = _engine(evict_to_host=True, resident_limit=4)
-        r = eng.submit(np.arange(6), max_new_tokens=3)
-        eng.run_until_drained()
-        before = _cache_page(eng, 0)
-        assert r.rid in eng.resident_store and r.rid not in eng.host_store
-        _zero_slot(eng, 0)
-        eng.restore_request(r.rid, 0)
-        after = _cache_page(eng, 0)
-        for name, page in before.items():
-            np.testing.assert_array_equal(after[name], page)
+from repro.serving.engine import GraphStreamServer
 
 
 class TestGraphStreamServer:
@@ -143,42 +47,8 @@ class TestGraphStreamServer:
 
 
 class TestMetricsSurface:
-    """ISSUE 7: both serving front-ends expose one registry-backed scrape
-    surface; the legacy stats objects are live views of the same registry."""
-
-    def test_engine_metrics_text_round_trips_and_matches_stats(self):
-        from repro.obs import parse_metrics_text
-        eng = _engine(evict_to_host=True)
-        eng.submit(np.arange(8), max_new_tokens=4)
-        eng.run_until_drained()
-        fams = parse_metrics_text(eng.metrics_text())
-        assert fams["smof_engine_prefills_total"]["samples"][
-            "smof_engine_prefills_total"] == eng.stats.prefills == 1
-        assert eng.stats.decode_steps > 0 and eng.stats.generated > 0
-        assert eng.stats.evicted_pages > 0
-        # BFP8 eviction compresses: compressed bytes < raw bytes, and both
-        # land as one labeled family
-        assert 0 < eng.stats.evicted_bytes_compressed \
-            < eng.stats.evicted_bytes_raw
-        kinds = fams["smof_engine_evicted_bytes_total"]["samples"]
-        assert kinds['smof_engine_evicted_bytes_total{kind="raw"}'] \
-            == eng.stats.evicted_bytes_raw
-        # request latency is a real histogram family on the same surface
-        assert fams["smof_engine_request_latency_seconds"]["type"] \
-            == "histogram"
-        # the legacy .latency attr and the registry read one histogram
-        assert eng.latency.n == fams["smof_engine_request_latency_seconds"][
-            "samples"]["smof_engine_request_latency_seconds_count"] == 1
-
-    def test_engine_stats_report_is_the_registry_snapshot(self):
-        eng = _engine()
-        eng.submit(np.arange(4), max_new_tokens=2)
-        eng.run_until_drained()
-        rep = eng.stats.report()
-        assert set(rep) <= set(eng.metrics.snapshot())
-        assert all(k.startswith("smof_engine_") for k in rep)
-        assert rep["smof_engine_prefills_total"] == 1.0
-        assert "smof_engine_prefills_total" in repr(eng.stats)
+    """The stream server exposes a registry-backed scrape surface; its
+    stats object is a live view of the same registry."""
 
     def test_stream_server_metrics_text_round_trips(self):
         from repro.obs import parse_metrics_text

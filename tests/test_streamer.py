@@ -395,16 +395,18 @@ class TestThroughput:
         _sequential_outputs(low, xs)
 
         def frame_time(fn):
-            best = math.inf
-            for _ in range(5):
-                t0 = time.perf_counter()
-                fn()
-                best = min(best, (time.perf_counter() - t0) / B)
-            return best
+            t0 = time.perf_counter()
+            fn()
+            return (time.perf_counter() - t0) / B
 
-        t_pipe = frame_time(lambda: sx(xs).block_until_ready())
-        t_seq = frame_time(
-            lambda: jax.block_until_ready([low(xs[b]) for b in range(B)]))
+        # best of 5 each, timed alternately, so a burst of load from
+        # another process lands on both sides rather than on one
+        t_pipe = t_seq = math.inf
+        for _ in range(5):
+            t_pipe = min(t_pipe,
+                         frame_time(lambda: sx(xs).block_until_ready()))
+            t_seq = min(t_seq, frame_time(lambda: jax.block_until_ready(
+                [low(xs[b]) for b in range(B)])))
         lat = measured_stage_latencies(sx, xs[0])
         e5 = eq5_sequential_time(lat)
         e6 = eq6_pipeline_time(lat)
