@@ -44,10 +44,10 @@ from ...memory import ChannelConfig, MemoryModel, build_memory_model
 from ...obs.modelcheck import ModelCheck, check_stream
 from ...obs.stream import StreamTracer
 from ...obs.trace import NULL_RECORDER, scope
-from ..executor import (BFP8_BLOCK, PlanAnalysis, SpillReport, _exec_spec,
-                        _make_offchip_hop, analyze_plan, bfp8_spill_decode,
-                        bfp8_spill_encode, init_params, resolve_kernel_mode,
-                        run_vertices, weight_shape)
+from ..executor import (BFP8_BLOCK, LANES, PlanAnalysis, SpillReport,
+                        _exec_spec, _make_offchip_hop, analyze_plan,
+                        bfp8_spill_decode, bfp8_spill_encode, init_params,
+                        resolve_kernel_mode, run_vertices, weight_shape)
 from . import queues as Q
 from . import schedule as SCH
 
@@ -76,6 +76,13 @@ class StreamReport(SpillReport):
     stage_stalls: list[int] = dataclasses.field(default_factory=list)
     stage_latency: list[float] = dataclasses.field(default_factory=list)
     queue_stats: dict = dataclasses.field(default_factory=dict)
+    #: output assembly (``placement="interleave"``): the scan's stacked
+    #: buffer ``(ticks, n, 128)``, the zeros padded onto each frame's output
+    #: to fill it, and the HBM bytes a call writes there plus the one
+    #: relayout to ``(B, L)`` reads and writes; ``None`` and 0 on the ring
+    emit_rows: tuple[int, int, int] | None = None
+    emit_pad: int = 0
+    emit_bytes_per_call: int = 0
     #: the off-chip channel view (``repro.memory``) when the plan was
     #: lowered with a :class:`~repro.memory.ChannelConfig`; ``None`` keeps
     #: every contended property degrading to its uncontended twin.
@@ -137,6 +144,9 @@ class StreamReport(SpillReport):
             "eq5_time": self.eq5_time,
             "eq6_time": self.eq6_time,
             "bottleneck_stage": self.bottleneck_stage,
+            "emit_rows": self.emit_rows,
+            "emit_pad": self.emit_pad,
+            "emit_bytes_per_call": self.emit_bytes_per_call,
         })
         if self.memory is not None:
             out.update({
@@ -523,8 +533,8 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
     # Scopes: ``smof.tick`` (one tick's work), inside it ``smof.tick.read``
     # (the tick's frame and crossing decodes) and ``smof.tick.carry`` (the
     # delay-line shift); ``smof.emit`` (around the scan, so that only the
-    # scan's stacking of each tick's output into the ``(B, L)`` result and
-    # the final slice have it innermost).
+    # scan's stacking of each tick's output and the one relayout of the
+    # stack into the ``(B, L)`` result have it innermost).
     def tick_body(params, carry, t, xs):
         with scope("tick"):
             with scope("tick.read"):
@@ -551,14 +561,31 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
                     for e in crossing}
             return new_carry, y
 
+    # Output assembly: each tick's ``y`` is stacked as ``(n, 128)`` rows of
+    # whole (8, 128) tiles, zero-padded where ``L`` is not a multiple of
+    # 1024, so a tick's write covers tiles of its own.  Stacked as ``(L,)``
+    # rows, one tick would touch a sublane of every tile of the
+    # ``(ticks, L)`` buffer, and XLA rewrites all of it each tick.
+    n_emit = -(-out_len // (8 * LANES)) * 8
+    pad_emit = n_emit * LANES - out_len
+
+    def as_tiles(y):
+        if pad_emit:
+            y = jnp.pad(y, (0, pad_emit))
+        return y.reshape(n_emit, LANES)
+
     def build_interleave():
         def step(params, xs):
             _check_stream_shape(xs)
+
+            def tick(c, t):
+                c, y = tick_body(params, c, t, xs)
+                return c, as_tiles(y)
             with scope("emit"):
-                _, ys = jax.lax.scan(
-                    lambda c, t: tick_body(params, c, t, xs),
-                    make_carry0(), jnp.arange(sched.ticks))
-                return ys[S - 1:]
+                _, ys = jax.lax.scan(tick, make_carry0(),
+                                     jnp.arange(sched.ticks))
+                ys = ys[S - 1:].reshape(B, n_emit * LANES)
+                return ys[:, :out_len] if pad_emit else ys
         return jax.jit(step)
 
     # -- multi-device ring: shard_map, one stage per device ------------------
@@ -676,6 +703,11 @@ def lower_plan_pipelined(g: Graph, plan: ExecutionPlan, *,
         queue_stats={f"{u}->{w}": st
                      for (u, w), st in sim["queues"].items()},
         memory=mem)
+    if placement == "interleave":
+        # ticks rows stacked, then B rows read and written by the relayout
+        report.emit_rows = (sched.ticks, n_emit, LANES)
+        report.emit_pad = pad_emit
+        report.emit_bytes_per_call = (sched.ticks + 2 * B) * n_emit * LANES * 4
 
     params = init_params(g, seed=seed)
     jitted_stage_fns = [jax.jit(functools.partial(_stage_call, f))

@@ -262,3 +262,30 @@ def test_pipelined_step_sends_lane_dense_arrays_to_host(topo, shape_of,
     assert len(to_host) == len(to_device) == sx.report.summary()["hop_arrays"]
     assert all(int(a.rstrip("]").split(",")[-1]) % 128 == 0
                for a in to_host + to_device), to_host
+
+
+def test_pipelined_step_stacks_its_output_in_whole_tiles(shape_of):
+    """Compiled for the chip, the scan of the small UNet's step stacks each
+    tick's output into a rank-3 buffer of 128-lane rows, ``(ticks, n,
+    128)``: a tick's write covers whole (8, 128) tiles of its own.  A
+    rank-2 ``(ticks, L)`` buffer would put 8 ticks in each tile, so that
+    every tick's write reads and writes the whole buffer."""
+    import re
+
+    from repro.core import build_unet_exec
+    from repro.core.builders import exec_input_shape
+    from repro.runtime.streamer import lower_plan_pipelined
+
+    g = build_unet_exec(positions=POSITIONS // 16, levels=2)
+    sx = lower_plan_pipelined(g, _one_skip_plan(g), microbatches=2,
+                              kernel_mode="pallas", interpret=False)
+    params = {k: shape_of(v.shape) for k, v in sx.params.items()}
+    xs = shape_of((2,) + exec_input_shape(g))
+    text = sx.fn.lower(params, xs).compile().as_text()
+    stacked = re.findall(
+        r"= f32\[([\d,]+)\]\{[^}]*\} dynamic-update-slice\([^\n]*"
+        r'op_name="jit\(step\)/smof\.emit/while/body/', text)
+    assert stacked, "no stacking update in the step's scan"
+    shapes = [tuple(map(int, s.split(","))) for s in stacked]
+    assert all(len(s) == 3 and s[-1] == 128 for s in shapes), shapes
+    assert shapes[0] == sx.report.emit_rows

@@ -309,6 +309,72 @@ class TestStreamReport:
 
 
 # =============================================================================
+# Output assembly: each tick's output stacked in whole (8, 128) tiles
+# =============================================================================
+
+# (builder, L, rows of 128 lanes, zeros padded per frame): the UNet's output
+# is 64 positions x 32 classes = 2048 = 2 x 1024, so no pad; the YOLO head
+# concatenates three scales, (64 + 32 + 16) x 32 = 3584, padded to 4 x 1024
+EMIT_CASES = [(build_unet_exec, 2048, 16, 0),
+              (build_yolo_head_exec, 3584, 32, 512)]
+
+
+def _scans(jaxpr):
+    """The ``scan`` equations of ``jaxpr`` and of the calls it makes, not
+    of the scans' own bodies."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+            continue
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                yield from _scans(sub)
+
+
+class TestOutputAssembly:
+    B = 4
+
+    def _lower(self, build):
+        g = build()
+        plan = _staged_plan(g)
+        sx = lower_plan_pipelined(g, plan, microbatches=self.B,
+                                  kernel_mode="reference")
+        xs = jax.random.normal(jax.random.PRNGKey(5), (self.B, 64, 32),
+                               jnp.float32)
+        return g, plan, sx, xs
+
+    @pytest.mark.parametrize("build,L,rows,pad", EMIT_CASES)
+    def test_stacked_tiles_return_the_staged_outputs_bit_for_bit(
+            self, build, L, rows, pad):
+        g, plan, sx, xs = self._lower(build)
+        ys = np.asarray(sx(xs))
+        assert ys.shape == (self.B, L)
+        low = lower_plan(g, plan, kernel_mode="reference")
+        np.testing.assert_array_equal(ys, _sequential_outputs(low, xs))
+
+    @pytest.mark.parametrize("build,L,rows,pad", EMIT_CASES)
+    def test_scan_stacks_rank3_rows_of_128_lanes(self, build, L, rows, pad):
+        _, _, sx, xs = self._lower(build)
+        closed = jax.make_jaxpr(sx.fn)(sx.params, xs)
+        [scan] = list(_scans(closed.jaxpr))
+        [stacked] = scan.outvars[scan.params["num_carry"]:]
+        assert stacked.aval.shape == (sx.report.ticks, rows, 128)
+
+    @pytest.mark.parametrize("build,L,rows,pad", EMIT_CASES)
+    def test_report_counts_the_stacked_rows_and_bytes(self, build, L, rows,
+                                                      pad):
+        _, _, sx, _ = self._lower(build)
+        ticks = self.B + 3 - 1                    # 3 stages
+        s = sx.report.summary()
+        assert s["emit_rows"] == (ticks, rows, 128)
+        assert s["emit_pad"] == pad == rows * 128 - L
+        # each tick writes one row of rows x 128 float32; the relayout
+        # reads and writes B of them once
+        assert s["emit_bytes_per_call"] == (ticks + 2 * self.B) * rows * 512
+
+
+# =============================================================================
 # ModelCheck: measured walk vs the Eq. 5/6 schedule and Eq. 1 queue sizing
 # =============================================================================
 
